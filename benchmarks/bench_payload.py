@@ -40,7 +40,7 @@ def main(n: int = None) -> List[Tuple[str, float, str]]:  # noqa: ARG001
     from repro.core.index import CentralizedIndex
     from repro.core.store import BandwidthResource
     from repro.diffusion.payload import RealPayload
-    from repro.diffusion.tiers import TieredStore, TierSpec, roofline_tier_bw
+    from repro.diffusion.tiers import TieredStore, TierSpec
     from repro.diffusion.transfer import TransferEngine
 
     page_bytes = int(PAGE_MIB * 1024 * 1024)
@@ -96,11 +96,11 @@ def main(n: int = None) -> List[Tuple[str, float, str]]:  # noqa: ARG001
 
         rows: List[Tuple[str, float, str]] = []
         history_edges = {}
+        roofline = backend.measured.tier_roofline()
         for r in backend.measured.rows():
             edge = f"{r['src']}->{r['dst']}"
             gbps = r["bytes_per_s"] / 1e9
-            roof = min(roofline_tier_bw(r["src"]),
-                       roofline_tier_bw(r["dst"])) / 1e9
+            roof = min(roofline(r["src"]), roofline(r["dst"])) / 1e9
             history_edges[edge] = round(gbps, 3)
             rows.append((
                 f"payload_roundtrip/{edge}",

@@ -224,7 +224,7 @@ def measured_swapin_case(pages: int = 8, page_mib: float = 4.0,
     import numpy as np
     import jax.numpy as jnp
     from repro.diffusion.payload import RealPayload
-    from repro.diffusion.tiers import TieredStore, TierSpec, roofline_tier_bw
+    from repro.diffusion.tiers import TieredStore, TierSpec
 
     backend = RealPayload("serve")
     store = TieredStore(
@@ -255,14 +255,14 @@ def measured_swapin_case(pages: int = 8, page_mib: float = 4.0,
             f"serve_batch[measured_swapin]: {violations}")
     edges = {f"{r['src']}->{r['dst']}": r for r in backend.measured.rows()}
     swap = edges.get("dram->hbm")
+    roofline = backend.measured.tier_roofline()
     if swap is None or swap["moves"] == 0:
         raise RuntimeError(
             "serve_batch[measured_swapin]: no dram->hbm swap-in was "
             "measured (payload plane not engaged)")
     return {
         "gbps": swap["bytes_per_s"] / 1e9,
-        "roofline_gbps": min(roofline_tier_bw("dram"),
-                             roofline_tier_bw("hbm")) / 1e9,
+        "roofline_gbps": min(roofline("dram"), roofline("hbm")) / 1e9,
         "moves": swap["moves"],
         "bytes": swap["bytes"],
         "us_per_move": 1e6 * swap["seconds"] / swap["moves"],

@@ -9,7 +9,8 @@ runs: importing this module (or starting a ``--smoke`` / ``--only`` run)
 must not pay for the JAX-heavy benches (roofline/model-error pull in the
 launch/model stack), so the smoke gate starts in a couple of seconds on a
 bare CPU install and an import-time failure in one bench degrades to that
-suite's ERROR row instead of killing the whole harness.
+suite's ERROR row instead of killing the whole harness.  The harness still
+runs every suite, then exits nonzero if any printed an ERROR row.
 """
 
 from __future__ import annotations
@@ -21,6 +22,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper scale (250K tasks)")
     ap.add_argument("--quick", action="store_true", help="CI scale (6K tasks)")
@@ -90,6 +93,7 @@ def main() -> None:
     ]
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")
+    failed = []
     for name, mod_name, arg in suites:
         if only and name not in only:
             continue
@@ -101,7 +105,10 @@ def main() -> None:
                 print(",".join(str(x) for x in row), flush=True)
         except Exception as e:  # noqa: BLE001 — keep the suite running
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         print(f"# suite {name} took {time.time() - t0:.1f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"# {len(failed)} suite(s) failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

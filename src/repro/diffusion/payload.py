@@ -33,7 +33,9 @@ promote/demote/fetch decisions (asserted in ``tests/test_payload.py``).
 
 ``MeasuredBandwidth`` accumulates bytes/seconds per (src tier, dst tier)
 edge; ``check_roofline`` flags any edge whose *aggregate* measured bandwidth
-exceeds ``factor``x the roofline of its slower endpoint — measured transfers
+exceeds ``factor``x the roofline of its slower endpoint — the peaks of the
+accelerator the bytes moved on (``launch.rooflines``, by ``device_kind``),
+or the DES's modeled tier calibration for host-only runs — measured transfers
 can be slower than roofline (overheads), but 10x faster is always a timing
 bug (an unblocked async copy), which is exactly what the
 ``payload_roundtrip`` smoke row turns into an ERROR.
@@ -69,6 +71,9 @@ class MeasuredBandwidth:
     def __init__(self) -> None:
         # (src, dst) -> [bytes, seconds, moves]
         self._acc: Dict[Tuple[str, str], List[float]] = {}
+        # device_kind of the accelerator the bytes moved on (None: host-only
+        # run, e.g. the CPU backend or FakePayload's modeled seconds)
+        self.device_kind: Optional[str] = None
 
     def record(self, src: str, dst: str, nbytes: float, seconds: float) -> None:
         ent = self._acc.setdefault((src, dst), [0.0, 0.0, 0.0])
@@ -99,23 +104,36 @@ class MeasuredBandwidth:
         return out
 
     def merge(self, other: "MeasuredBandwidth") -> None:
+        self.device_kind = self.device_kind or other.device_kind
         for (src, dst), (b, s, n) in other._acc.items():
             ent = self._acc.setdefault((src, dst), [0.0, 0.0, 0.0])
             ent[0] += b
             ent[1] += s
             ent[2] += n
 
+    def tier_roofline(self) -> Callable[[str], float]:
+        """Tier -> peak bytes/s: the measured device's peaks (hbm: its HBM,
+        dram: its host link; an unknown ``device_kind`` raises), else the
+        DES's modeled tier calibration."""
+        if self.device_kind is None:
+            from .tiers import roofline_tier_bw  # deferred: import cycle
+            return roofline_tier_bw
+        from ..launch.rooflines import DISK_BW, device_peaks
+        peaks = device_peaks(self.device_kind)
+        bw = {"hbm": peaks.hbm_bw, "dram": peaks.host_link_bw}
+        return lambda tier: bw.get(tier, DISK_BW)
+
     def check_roofline(self, factor: float = 10.0) -> List[str]:
         """Edges measured impossibly fast: aggregate bandwidth more than
         ``factor``x the roofline of the edge's slower physical endpoint.
         Returns violation strings (empty = sane); slower-than-roofline is
         normal and never flagged."""
-        from .tiers import roofline_tier_bw  # deferred: avoids import cycle
+        roofline = self.tier_roofline()
         bad = []
         for (src, dst) in sorted(self._acc):
             if src not in _ROOFLINE_TIERS or dst not in _ROOFLINE_TIERS:
                 continue
-            roof = min(roofline_tier_bw(src), roofline_tier_bw(dst))
+            roof = min(roofline(src), roofline(dst))
             bw = self.bandwidth(src, dst)
             if bw > factor * roof:
                 bad.append(
@@ -311,7 +329,11 @@ class RealPayload(PayloadBackend):
     # -- physical homes -------------------------------------------------------
     def _to_device(self, leaves: List[Any]) -> List[Any]:
         import jax
-        out = [jax.device_put(l, self.device) for l in leaves]
+        device = self.device if self.device is not None else jax.devices()[0]
+        if device.platform != "cpu":
+            # bytes land on an accelerator: hold them to its own peaks
+            self.measured.device_kind = device.device_kind
+        out = [jax.device_put(l, device) for l in leaves]
         return [jax.block_until_ready(l) for l in out]
 
     def _to_host(self, obj: str) -> List[np.ndarray]:

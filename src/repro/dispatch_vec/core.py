@@ -32,7 +32,8 @@ True)``) so it can ride the single-scan drain.
 Bulk (re)scoring — ``rebuild_scores()`` — runs the one-shot matmul on the
 materialized bitmaps: numpy always; ``score_backend="pallas"`` routes it
 through the tiled Pallas kernel in ``repro.kernels.dispatch_score`` (engaged
-for large window x executor x object extents on TPU; interpret mode on CPU).
+for large window x executor x object extents on TPU; compiled for the TPU
+only, so CPU callers take numpy).
 The incremental plane never needs it in steady state — it exists for
 bootstrap-from-snapshot, consistency verification, and the benchmark's
 kernel-vs-numpy comparison.
@@ -789,10 +790,11 @@ class VectorizedDispatcher(DataAwareDispatcher):
 
         Returns (Sb, Sw) for active rows (row-id order).  ``backend`` falls
         back to ``self.score_backend``; "pallas" runs the tiled scoring
-        kernel from ``repro.kernels.dispatch_score`` (float32, interpret
-        mode off-TPU), "numpy" the float64 BLAS path.  With ``apply=True``
-        the incremental matrices are overwritten — the recovery path after
-        adopting a pre-populated index snapshot.
+        kernel from ``repro.kernels.dispatch_score`` (float32, compiled
+        for the accelerator: it has no interpret-mode fallback, so off-TPU
+        callers pass "numpy"), "numpy" the float64 BLAS path.  With
+        ``apply=True`` the incremental matrices are overwritten — the
+        recovery path after adopting a pre-populated index snapshot.
         """
         backend = backend or self.score_backend
         rows, dm = self.demand_matrix()
@@ -814,14 +816,15 @@ class VectorizedDispatcher(DataAwareDispatcher):
 
     # -------------------------------------------------------- device mirror
     def attach_device_mirror(self, backend: str = "numpy",
-                             interpret: bool = True):
+                             interpret: bool = False):
         """Install (or replace) the device-resident Sw shadow.
 
         ``backend="pallas"`` holds a jax device array updated per flush
-        epoch by the rank-K Pallas kernel (``interpret=True`` = CPU
-        correctness path); ``backend="numpy"`` is the jax-free float32
-        shadow tier-1 tests drive.  Returns the mirror; the caller owns the
-        flush cadence (one flush per drain epoch is the intended shape).
+        epoch by the rank-K Pallas kernel (compiled for the accelerator;
+        ``interpret=True`` is the CPU correctness path);
+        ``backend="numpy"`` is the jax-free float32 shadow tier-1 tests
+        drive.  Returns the mirror; the caller owns the flush cadence (one
+        flush per drain epoch is the intended shape).
         """
         from .device_mirror import DeviceScoreMirror
         self._mirror = DeviceScoreMirror(self, backend=backend,

@@ -84,7 +84,7 @@ class DeviceScoreMirror:
     """
 
     def __init__(self, dispatcher, backend: str = "numpy",
-                 interpret: bool = True):
+                 interpret: bool = False):
         if backend not in ("numpy", "pallas"):
             raise ValueError(f"backend must be numpy|pallas, got {backend!r}")
         self.backend = backend

@@ -1,9 +1,12 @@
 """Pallas TPU flash attention: tiled online-softmax GQA with causal skip.
 
-Grid (B, H, Sq/BQ, Skv/BK); the KV axis is the minor (sequential) dimension —
-running max/sum/accumulator live in VMEM scratch across KV iterations for a
-fixed (b, h, q-block).  Blocks fully above the causal diagonal (and fully
-outside the sliding window) are skipped with ``pl.when`` — this is the
+Grid (B, H, Sq/BQ, Skv/BK) over head-major [B, H, S, D] views (the wrapper
+transposes), so each block's last two dims are (BQ or BK, D): a multiple of
+8 by the full head dim, the tiling the TPU lowering requires.  The KV axis
+is the minor (sequential) dimension — running max/sum/accumulator live in
+VMEM scratch across KV iterations for a fixed (b, h, q-block).  Blocks
+fully above the causal diagonal (and fully outside the sliding window) are
+skipped with ``pl.when`` — this is the
 schedule that removes the 2x causal FLOP waste of the chunked-jnp lowering
 path, and the VMEM residency that removes its HBM score traffic.
 
@@ -50,9 +53,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :]
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                            # [BQ, BK]
@@ -81,7 +84,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ik == nk - 1)
     def _flush():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
@@ -111,16 +114,17 @@ def flash_attention_pallas(
         cparams = pltpu.TPUCompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         )
-    return pl.pallas_call(
+    qh, kh, vh = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))   # [B, H, S, D]
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // rep, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // rep, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h // rep, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h // rep, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
@@ -128,4 +132,5 @@ def flash_attention_pallas(
         ],
         compiler_params=cparams,
         interpret=interpret,
-    )(q, k, v)
+    )(qh, kh, vh)
+    return jnp.swapaxes(out, 1, 2)

@@ -39,26 +39,24 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *, chunk: int)
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    r = r_ref[0].astype(jnp.float32)            # [CT, N]
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)            # [N]
-    n = r.shape[-1]
-
-    logw = jnp.log(jnp.maximum(w, 1e-38))
+    u = u_ref[0].astype(jnp.float32)            # [1, N]
+    n = u.shape[-1]
     ti = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 1)
     lower = si < ti
+    # Inclusive prefix sum over the sub-chunk as a matmul with a triangle of
+    # ones (the TPU lowering has no cumsum); HIGHEST keeps it f32-exact.
+    incl = (si <= ti).astype(jnp.float32)
 
-    def sub_body(i, carry):
-        S, out = carry
-        start = i * SUB
-        rs = jax.lax.dynamic_slice(r, (start, 0), (SUB, n))
-        ks = jax.lax.dynamic_slice(k, (start, 0), (SUB, n))
-        vs = jax.lax.dynamic_slice(v, (start, 0), (SUB, n))
-        lw = jax.lax.dynamic_slice(logw, (start, 0), (SUB, n))
-        L = jnp.cumsum(lw, axis=0)              # local reference: exact
+    def sub_body(i, S):
+        rows = pl.ds(pl.multiple_of(i * SUB, SUB), SUB)
+        rs = r_ref[0, rows, :].astype(jnp.float32)     # [SUB, N]
+        ks = k_ref[0, rows, :].astype(jnp.float32)
+        vs = v_ref[0, rows, :].astype(jnp.float32)
+        lw = jnp.log(jnp.maximum(w_ref[0, rows, :].astype(jnp.float32), 1e-38))
+        L = jax.lax.dot_general(incl, lw, (((1,), (0,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
         Lprev = L - lw
         a = rs * jnp.exp(Lprev)
         b = ks * jnp.exp(-L)
@@ -67,21 +65,16 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *, chunk: int)
         A = jnp.where(lower, A, 0.0)
         intra = jax.lax.dot_general(A, vs, (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-        diag = jnp.sum(rs * u[None, :] * ks, axis=-1, keepdims=True) * vs
+        diag = jnp.sum(rs * u * ks, axis=-1, keepdims=True) * vs
         inter = jax.lax.dot_general(a, S, (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-        out = jax.lax.dynamic_update_slice(out, intra + diag + inter, (start, 0))
+        o_ref[0, rows, :] = (intra + diag + inter).astype(o_ref.dtype)
         l_last = L[-1:, :]
         kdec = ks * jnp.exp(l_last - L)
-        S = jnp.exp(l_last).T * S + jax.lax.dot_general(
+        return jnp.exp(l_last).T * S + jax.lax.dot_general(
             kdec, vs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return (S, out)
 
-    S0 = s_ref[...]
-    out0 = jnp.zeros((chunk, n), jnp.float32)
-    S, out = jax.lax.fori_loop(0, chunk // SUB, sub_body, (S0, out0))
-    o_ref[0] = out.astype(o_ref.dtype)
-    s_ref[...] = S
+    s_ref[...] = jax.lax.fori_loop(0, chunk // SUB, sub_body, s_ref[...])
 
 
 def wkv6_pallas(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
@@ -94,7 +87,9 @@ def wkv6_pallas(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
         return jnp.moveaxis(a, 2, 1).reshape(B * H, T, N)
 
     rf, kf, vf, wf = (flat(a) for a in (r, k, v, w))
-    uf = jnp.broadcast_to(u[None], (B, H, N)).reshape(B * H, N)
+    # u as (H, 1, N): a (1, N) block is the full last two dims, as the TPU
+    # tiling requires; program bh = b * H + h reads head h's row.
+    uf = u.reshape(H, 1, N)
     grid = (B * H, T // chunk)
     try:
         cparams = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
@@ -108,7 +103,7 @@ def wkv6_pallas(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
             pl.BlockSpec((1, chunk, N), lambda bh, it: (bh, it, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, it: (bh, it, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, it: (bh, it, 0)),
-            pl.BlockSpec((1, N), lambda bh, it: (bh, 0)),
+            pl.BlockSpec((1, 1, N), lambda bh, it: (bh % H, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, N), lambda bh, it: (bh, it, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, N), jnp.float32),

@@ -11,7 +11,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from ..models.sharding import ShardCtx
 
@@ -19,7 +19,7 @@ from ..models.sharding import ShardCtx
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_ctx(mesh: Mesh) -> ShardCtx:
@@ -32,4 +32,5 @@ def make_host_mesh(n_devices: int = 0, model_axis: int = 1) -> Mesh:
     """Small mesh over whatever devices exist (tests / examples)."""
     n = n_devices or len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

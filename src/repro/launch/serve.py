@@ -12,9 +12,11 @@ import numpy as np
 
 from ..configs import get_arch
 from ..runtime.serve_loop import DiffusionServer
+from .compile_cache import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

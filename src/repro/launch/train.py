@@ -21,10 +21,12 @@ from ..configs.base import ShapeConfig
 from ..models.sharding import ShardCtx
 from ..optim.adamw import AdamWConfig
 from ..runtime.train_loop import TrainConfig, Trainer
+from .compile_cache import use_compile_cache
 from .mesh import make_ctx, make_host_mesh
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",

@@ -301,13 +301,19 @@ def lm_loss(params, batch, cfg: ArchConfig, ctx: ShardCtx = ShardCtx(), chunk: i
     return loss + 0.01 * aux, {"loss": loss, "aux": aux}
 
 
-def lm_prefill(params, batch, cfg: ArchConfig, ctx: ShardCtx = ShardCtx(), chunk: int = 1024):
-    """Full-sequence forward building decode caches. Returns (logits_last, caches)."""
+def lm_hidden(params, batch, cfg: ArchConfig, ctx: ShardCtx = ShardCtx(), chunk: int = 1024):
+    """Cache-free full-sequence forward. Returns (final-normed hidden states
+    [B, S, D] at every position, decode caches)."""
     h, off = _embed_input(params, batch, cfg, ctx)
     positions = jnp.arange(h.shape[1])
     h, _, caches = _run_stack(params, h, cfg=cfg, ctx=ctx, positions=positions,
                               mode="prefill", chunk=chunk)
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return rmsnorm(params["final_norm"], h, cfg.norm_eps), caches
+
+
+def lm_prefill(params, batch, cfg: ArchConfig, ctx: ShardCtx = ShardCtx(), chunk: int = 1024):
+    """Full-sequence forward building decode caches. Returns (logits_last, caches)."""
+    h, caches = lm_hidden(params, batch, cfg, ctx, chunk)
     logits = logits_head(params, h[:, -1:, :], cfg.vocab_size)
     return logits[:, 0, :], caches
 

@@ -169,12 +169,7 @@ def moe_ffn_sharded(p, x, *, n_experts: int, top_k: int, capacity_factor: float,
         P(tp, None, None),               # w2 [E(tp), F, D]
     )
     out_specs = (P(dp_spec, tp, None), P())
-    try:
-        smap = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        smap = _sm(inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    smap = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
     w = p["experts"]
     return smap(x, p["router"], w["w1"], w["w3"], w["w2"])

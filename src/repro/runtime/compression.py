@@ -86,10 +86,5 @@ def compressed_psum(grads, mesh, axis: str = "pod"):
         return jax.tree_util.tree_map(one, g)
 
     spec = jax.tree_util.tree_map(lambda _: P(), grads)
-    try:
-        return jax.shard_map(inner, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, check_vma=False)(grads)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(inner, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                   check_rep=False)(grads)
+    return jax.shard_map(inner, mesh=mesh, in_specs=(spec,),
+                         out_specs=spec, check_vma=False)(grads)

@@ -9,15 +9,18 @@ transient-store accounting (``core.cache.Cache``), index publication, and
 DRP-driven elasticity all live in ``runtime.router.CacheAffinityRouter`` —
 this module owns only the model: params, prefill, decode, KV tensors.
 
-Runs for real on CPU with a reduced-config model (examples/serve_diffusion.py);
-the decode step is the same ``make_decode_step`` the dry-run lowers at scale.
+Runs for real on CPU with a reduced-config model (examples/serve_diffusion.py)
+and at full width on a TPU (chip_smoke.py); the decode step is the same
+``make_decode_step`` the dry-run lowers at scale.  Each replica lives on one
+local device — the least-used one when it is built — and its parameters,
+prompts, KV caches and payload homes stay there.
 """
 
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +48,11 @@ class Request:
     prefix_hit: bool = False
     tenant: str = ""                # multi-tenant admission account
     verdict: Optional[Any] = None   # AdmissionVerdict when admission is on
+    # Greedy token ids ([1] device arrays, one per decode step) and the
+    # last step's logits, left on the device: read them after the request
+    # so serving adds no host sync per token.
+    generated: List[Any] = field(default_factory=list)
+    last_logits: Optional[Any] = None
 
     @property
     def response_time_s(self) -> Optional[float]:
@@ -54,18 +62,22 @@ class Request:
 
 
 class Replica:
-    """One model replica: params + per-session KV tensors.
+    """One model replica on one device: params + per-session KV tensors.
 
     Which sessions *may* live here (capacity, eviction order) is decided by
     the router's ``ReplicaStore``; this class just holds the payloads.
     """
 
-    def __init__(self, name: str, cfg: ArchConfig, params, cap: int):
+    def __init__(self, name: str, cfg: ArchConfig, params, cap: int,
+                 device: Any):
         self.name = name
         self.cfg = cfg
-        self.params = params
+        self.params = params            # committed to ``device``
         self.cap = cap
-        self.sessions: Dict[str, Dict[str, Any]] = {}  # sid -> {caches, pos}
+        self.device = device
+        # sid -> {caches, pos}; caches is None where the payload plane owns
+        # the KV (payload="real"), so a demotion frees the device copy.
+        self.sessions: Dict[str, Dict[str, Any]] = {}
 
     def has_session(self, sid: str) -> bool:
         return sid in self.sessions
@@ -164,6 +176,9 @@ class DiffusionServer:
         tenant_quota_frac: float = 0.0,
         ctx: ShardCtx = ShardCtx(),
         seed: int = 0,
+        # Devices replicas are placed on (least-used first); default: every
+        # local device, so a four-chip host runs one replica per chip.
+        devices: Optional[Sequence[Any]] = None,
     ):
         if payload not in ("modeled", "real"):
             raise ValueError(f"payload must be 'modeled' or 'real': {payload!r}")
@@ -173,6 +188,11 @@ class DiffusionServer:
         self.payload_mode = payload
         self.measured = MeasuredBandwidth()
         self.params = init_params(cfg, jax.random.PRNGKey(seed))
+        self.devices = list(devices) if devices else jax.local_devices()
+        # One parameter copy per device, shared by that device's replicas.
+        home, = jax.tree_util.tree_leaves(self.params)[0].devices()
+        self._device_params: Dict[Any, Any] = {home: self.params}
+        self._placement: Dict[str, Any] = {}    # replica name -> device
         shape = ShapeConfig("serve", "prefill", cache_cap, 1)
         self.prefill_fn = jax.jit(make_prefill_step(cfg, shape, ctx))
         self.decode_fn = jax.jit(make_decode_step(cfg, ctx))
@@ -224,6 +244,7 @@ class DiffusionServer:
                 # failing the request: drop the copy, quarantine, re-fetch.
                 (lambda name: RealPayload(name=name, measured=self.measured,
                                           spill_dir=spill_dir,
+                                          device=self._device_for(name),
                                           corrupt_mode="recover"))
                 if payload == "real" and tier_specs is not None else None),
             obs=obs,
@@ -248,12 +269,26 @@ class DiffusionServer:
         self._req_id = 0
 
     # ---------------------------------------------------------- replicas
+    def _device_for(self, name: str) -> Any:
+        """The replica's device: assigned once, least-used first (ties go to
+        the earlier device), so replicas spread one per device."""
+        if name not in self._placement:
+            used = list(self._placement.values())
+            self._placement[name] = min(self.devices, key=used.count)
+        return self._placement[name]
+
     def _build_replica(self, name: str) -> None:
-        self.replicas[name] = Replica(name, self.cfg, self.params, self.cap)
+        device = self._device_for(name)
+        if device not in self._device_params:
+            self._device_params[device] = jax.device_put(self.params, device)
+        self.replicas[name] = Replica(name, self.cfg,
+                                      self._device_params[device], self.cap,
+                                      device)
 
     def _drop_replica(self, name: str) -> None:
         """Router idle-released the replica: free its KV payloads too."""
         self.replicas.pop(name, None)
+        self._placement.pop(name, None)
 
     def _on_session_evicted(self, replica: str, obj: str) -> None:
         rep = self.replicas.get(replica)
@@ -266,7 +301,7 @@ class DiffusionServer:
         while len(self.replicas) > n:
             name = next(reversed(self.replicas))
             self.router.remove_replica(name)
-            del self.replicas[name]
+            self._drop_replica(name)
         self.router.drp.registered = n
 
     def swap_in_bandwidth(self) -> float:
@@ -316,49 +351,54 @@ class DiffusionServer:
         req: Request = routed.payload
         req.replica = replica.name
         sid = req.session_id
+        obj = session_object(sid)
         use_cache = self.router.dispatcher.provides_location_info()
         state = replica.sessions.get(sid) if use_cache else None
+        store = self.router.stores.get(replica.name)
+        # payload="real": the store's physical KV plane is the only owner of
+        # a resident session's KV, so an HBM eviction that demotes the
+        # tensors to host memory frees the device copy.
+        backend = (store.tiers.payload
+                   if self.payload_mode == "real" and store is not None
+                   else None)
+        caches = None
         if routed.hits and state is not None:
-            req.prefix_hit = True
-            self.stats.prefix_hits += 1
             # Charge restore by the tier the prefix was found in: an HBM hit
             # continues in place for free; a lower-tier (host DRAM) hit is a
             # swap-in — far cheaper than a prefill replay, but not free.
-            found = routed.sources.get(session_object(sid))
-            store = self.router.stores.get(replica.name)
-            caches, pos = state["caches"], state["pos"]
-            if store is not None and found is not None and found != store.top_tier:
-                self.stats.swap_ins += 1
-                if self.payload_mode == "real":
-                    # The routing access already promoted the object, which
-                    # made the backend device_put the demoted host copy back
-                    # into HBM (timed into self.measured).  Decode must
-                    # continue on those swapped-in tensors, not on stale
-                    # device refs the eviction left behind.
-                    t0 = time.time()
-                    backend = store.tiers.payload
-                    restored = (backend.value(session_object(sid))
-                                if backend is not None else None)
-                    if restored is not None:
-                        caches = restored
-                        if self._trace is not None:
-                            # Structural span: the real KV bytes returning
-                            # to the device for this request.
-                            self._trace.record(
-                                routed.request_id, session_object(sid),
-                                "payload", t0, time.time(),
-                                replica=replica.name, parent="dispatch",
-                                detail=(found, store.top_tier))
-            self.stats.restore_time_s += routed.restore_cost_s
-        else:
+            found = routed.sources.get(obj)
+            swapped = (store is not None and found is not None
+                       and found != store.top_tier)
+            t0 = time.time()
+            # payload="real": the routing access already promoted a demoted
+            # prefix, which made the backend device_put the host copy back
+            # onto this replica's device (timed into self.measured).  None
+            # means a poisoned spill copy was dropped: replay the prompt.
+            caches = state["caches"] if backend is None else backend.value(obj)
+            if caches is not None:
+                req.prefix_hit = True
+                self.stats.prefix_hits += 1
+                pos = state["pos"]
+                if swapped:
+                    self.stats.swap_ins += 1
+                    if backend is not None and self._trace is not None:
+                        # Structural span: the real KV bytes returning to
+                        # the device for this request.
+                        self._trace.record(
+                            routed.request_id, obj, "payload", t0,
+                            time.time(), replica=replica.name,
+                            parent="dispatch", detail=(found, store.top_tier))
+                self.stats.restore_time_s += routed.restore_cost_s
+        if caches is None:
             # "copy from persistent storage": replay the prompt (prefill).
             self.stats.prefills += 1
             t0 = time.time()
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            batch = {"tokens": prompt}
-            _, pre_caches = self.prefill_fn(self.params, batch)
+            prompt = jax.device_put(np.asarray(req.prompt, np.int32)[None, :],
+                                    replica.device)
+            _, pre_caches = self.prefill_fn(replica.params, {"tokens": prompt})
             # prefill caches are full-seq; re-home into a decode cache buffer
-            caches = cache_init(self.cfg, 1, self.cap)
+            with jax.default_device(replica.device):
+                caches = cache_init(self.cfg, 1, self.cap)
             caches = _merge_prefill_caches(caches, pre_caches, self.cfg)
             pos = req.prompt.shape[0]
             if self._trace is not None:
@@ -372,15 +412,19 @@ class DiffusionServer:
                                    detail=(req.prompt.shape[0],))
 
         t0 = time.time()
-        token = jnp.asarray([int(req.prompt[-1]) % self.cfg.vocab_size], jnp.int32)
+        token = jax.device_put(
+            np.asarray([int(req.prompt[-1]) % self.cfg.vocab_size], np.int32),
+            replica.device)
         for _ in range(req.max_new_tokens):
             if pos >= self.cap - 1:
                 break
             logits, caches = self.decode_fn(
-                self.params, {"token": token, "pos": jnp.asarray(pos, jnp.int32),
-                              "caches": caches}
+                replica.params, {"token": token, "pos": np.int32(pos),
+                                 "caches": caches}
             )
             token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            req.generated.append(token)
+            req.last_logits = logits
             pos += 1
             self.stats.decode_steps += 1
         if self._trace is not None:
@@ -392,19 +436,16 @@ class DiffusionServer:
             # (first-available ships no location info and caches nothing;
             # pass-through objects larger than the store are never admitted,
             # so their payloads must not linger unaccounted either).
-            store = self.router.stores.get(replica.name)
-            if store is not None and store.contains(session_object(sid)):
-                replica.sessions[sid] = {"caches": caches, "pos": pos}
-                if self.payload_mode == "real":
-                    backend = store.tiers.payload
-                    if backend is not None:
-                        # Register/refresh the session's actual KV bytes in
-                        # the physical plane so later demotions/swap-ins
-                        # move real tensors (an untimed working-copy update,
-                        # not a tier move).
-                        obj = session_object(sid)
-                        backend.put(obj, caches,
-                                    store.tier_of(obj) or store.top_tier)
+            if store is not None and store.contains(obj):
+                replica.sessions[sid] = {
+                    "caches": caches if backend is None else None, "pos": pos}
+                if backend is not None:
+                    # Register/refresh the session's actual KV bytes in the
+                    # physical plane so later demotions/swap-ins move real
+                    # tensors (an untimed working-copy update, not a tier
+                    # move).
+                    backend.put(obj, caches,
+                                store.tier_of(obj) or store.top_tier)
             else:
                 replica.sessions.pop(sid, None)
         req.finish_time_s = time.time()

@@ -75,6 +75,39 @@ class TestMeasuredBandwidth:
         m2.record("dram", "hbm", roof * 0.01, 1.0)
         assert m2.check_roofline() == []
 
+    def test_roofline_check_holds_a_device_run_to_its_kind(self):
+        """A run on a real device is held to that device's host link, not
+        the DES's modeled dram calibration (ICI)."""
+        from repro.launch.rooflines import device_peaks
+        link = device_peaks("TPU v5 lite").host_link_bw
+        m = MeasuredBandwidth()
+        m.record("dram", "hbm", link * 12.0, 1.0)   # 12x the host link
+        assert m.check_roofline(factor=10.0) == []  # under 10x modeled ICI
+        m.device_kind = "TPU v5 lite"
+        bad = m.check_roofline(factor=10.0)
+        assert len(bad) == 1 and "dram->hbm" in bad[0]
+
+    def test_roofline_check_unknown_device_kind_is_an_error(self):
+        m = MeasuredBandwidth()
+        m.device_kind = "TPU v99"
+        m.record("dram", "hbm", 1.0, 1.0)
+        with pytest.raises(ValueError, match="TPU v99"):
+            m.check_roofline()
+
+    def test_peak_table_is_keyed_by_device_kind_with_a_source(self):
+        from repro.launch.rooflines import PEAKS, REFERENCE, device_peaks
+        for kind, peaks in PEAKS.items():
+            assert peaks.kind == kind and peaks.source
+            assert peaks.host_link_bw < peaks.hbm_bw
+        assert device_peaks("TPU v5 lite") is REFERENCE
+        assert REFERENCE.flops_bf16 == 197e12 and REFERENCE.hbm_bw == 819e9
+
+    def test_merge_keeps_the_device_kind(self):
+        a, b = MeasuredBandwidth(), MeasuredBandwidth()
+        b.device_kind = "TPU v5 lite"
+        a.merge(b)
+        assert a.device_kind == "TPU v5 lite"
+
     def test_roofline_check_skips_modeled_sources(self):
         # engine edges ("persistent"/"peer" -> tier) ride modeled wires; an
         # in-process memcpy legitimately beats them and must not be flagged.

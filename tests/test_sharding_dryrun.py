@@ -70,7 +70,8 @@ DISTRIBUTED_SCRIPT = textwrap.dedent("""
     from repro.models.sharding import ShardCtx, tree_shardings
 
     cfg = get_arch("{arch}").reduced()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ctx = ShardCtx(mesh=mesh, dp_axes=("data",), tp_axis="model")
     shape = ShapeConfig("t", "train", 64, 4)
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -117,7 +118,8 @@ def test_hlo_analyzer_collectives_small_mesh():
         import json, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze_compiled
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = jax.make_mesh((4,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         def f(x, w):
             return x @ w
         xs = jax.ShapeDtypeStruct((128, 256), jnp.float32,
@@ -146,7 +148,8 @@ MOE_EQUIV_SCRIPT = textwrap.dedent("""
     from repro.models.moe import moe_ffn, moe_ffn_sharded, moe_init
     from repro.models.sharding import ShardCtx
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ctx = ShardCtx(mesh=mesh, dp_axes=("data",), tp_axis="model")
     D, F, E, K = 32, 64, 8, 2
     B, S = 4, 16
