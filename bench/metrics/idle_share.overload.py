@@ -1,0 +1,5 @@
+"""``idle_share`` in the cell above the knee, where it moves ``tokens_per_s``."""
+
+
+def read(run):
+    return run.metric("idle_share")
