@@ -50,6 +50,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs.trace import span
+
 __all__ = [
     "MeasuredBandwidth",
     "PayloadBackend",
@@ -285,6 +287,11 @@ class _SpilledLeaf:
         self.chunks = chunks            # [(path, sha256 hexdigest), ...]
 
 
+# Physical homes from the top tier down (any other tier is host memory):
+# a move to a lower rank is a promotion, its span ``payload.promote``.
+_TIER_RANK = {"hbm": 0, "disk": 2}
+
+
 class RealPayload(PayloadBackend):
     """Physical KV homes: device arrays (hbm), host numpy (everything else),
     chunked spill files with verified digests (disk).
@@ -292,7 +299,10 @@ class RealPayload(PayloadBackend):
     Every timed edge that touches the device is closed with
     ``jax.block_until_ready`` before the clock stops — the measured
     bandwidth is the bytes actually landed, not the async dispatch.  jax is
-    imported lazily so modeled-only runs never pay for it.
+    imported lazily so modeled-only runs never pay for it.  Each move runs
+    in a ``payload.promote`` or ``payload.demote`` span by direction (disk
+    spills demote), each ``put`` and ``get`` in ``payload.put`` and
+    ``payload.get`` (``obs.trace.span``).
     """
 
     def __init__(
@@ -403,17 +413,19 @@ class RealPayload(PayloadBackend):
 
     # -- interface ------------------------------------------------------------
     def put(self, obj: str, value: Any, tier: str) -> None:
-        self.dropped(obj)               # re-put replaces (frees old spill)
-        leaves: List[Any] = []
-        template = _tree_leaves(value, leaves)
-        self._nbytes[obj] = _leaf_nbytes(leaves)
-        self._templates[obj] = template
-        if tier == "hbm":
-            self._leaves[obj] = self._to_device(leaves)
-        else:
-            host = [np.ascontiguousarray(np.asarray(l)) for l in leaves]
-            self._leaves[obj] = self._home(obj, host, tier)
-        self._tiers[obj] = tier
+        # On the device this waits for the computation that made ``value``.
+        with span("payload.put"):
+            self.dropped(obj)           # re-put replaces (frees old spill)
+            leaves: List[Any] = []
+            template = _tree_leaves(value, leaves)
+            self._nbytes[obj] = _leaf_nbytes(leaves)
+            self._templates[obj] = template
+            if tier == "hbm":
+                self._leaves[obj] = self._to_device(leaves)
+            else:
+                host = [np.ascontiguousarray(np.asarray(l)) for l in leaves]
+                self._leaves[obj] = self._home(obj, host, tier)
+            self._tiers[obj] = tier
 
     def _recover_corrupt(self, obj: str) -> None:
         """Poisoned spill copy: drop it (remaining chunks freed), notify the
@@ -426,13 +438,15 @@ class RealPayload(PayloadBackend):
     def get(self, obj: str) -> Optional[Any]:
         if obj not in self._leaves:
             return None
-        try:
-            host = self._to_host(obj)
-        except IOError:
-            if self.corrupt_mode != "recover":
-                raise
-            self._recover_corrupt(obj)
-            return None                 # degrades to placeholder semantics
+        # A host copy: what a peer fetch reads out of the source replica.
+        with span("payload.get"):
+            try:
+                host = self._to_host(obj)
+            except IOError:
+                if self.corrupt_mode != "recover":
+                    raise
+                self._recover_corrupt(obj)
+                return None             # degrades to placeholder semantics
         return _tree_rebuild(self._templates[obj], host)
 
     def value(self, obj: str) -> Optional[Any]:
@@ -468,17 +482,19 @@ class RealPayload(PayloadBackend):
         if src == tier:
             return
         old = self._leaves[obj]
-        t0 = time.perf_counter()
-        try:
-            host = self._to_host(obj)   # verified read out of the old home
-        except IOError:
-            if self.corrupt_mode != "recover":
-                raise
-            self._recover_corrupt(obj)
-            return                      # no move recorded; copy is gone
-        self._leaves[obj] = self._home(obj, host, tier)
-        dt = time.perf_counter() - t0
-        self._free_spill(old)
+        up = _TIER_RANK.get(tier, 1) < _TIER_RANK.get(src, 1)
+        with span("payload.promote" if up else "payload.demote"):
+            t0 = time.perf_counter()
+            try:
+                host = self._to_host(obj)   # verified read out of the old home
+            except IOError:
+                if self.corrupt_mode != "recover":
+                    raise
+                self._recover_corrupt(obj)
+                return                  # no move recorded; copy is gone
+            self._leaves[obj] = self._home(obj, host, tier)
+            dt = time.perf_counter() - t0
+            self._free_spill(old)
         self._tiers[obj] = tier
         self.measured.record(src, tier, self._nbytes[obj], dt)
 

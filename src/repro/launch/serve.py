@@ -149,7 +149,6 @@ def main() -> None:
     print(f"served={s.served} prefix_hit={s.hit_rate:.0%} prefills={s.prefills} "
           f"swap_ins={s.swap_ins} decode_steps={s.decode_steps} "
           f"replicas={len(srv.replicas)} scale_ups={r.scale_ups} "
-          f"avg_response={s.avg_response_s * 1e3:.1f}ms "
           # window-only percentiles (exact over the latency reservoir's
           # most recent samples, blind to older ones) — labeled as such.
           f"win_p50={r.p50_s * 1e3:.1f}ms win_p99={r.p99_s * 1e3:.1f}ms")

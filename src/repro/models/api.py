@@ -188,17 +188,25 @@ def make_train_step(cfg: ArchConfig, shape: ShapeConfig, ctx: ShardCtx = ShardCt
     return train_step
 
 
+def _named(fn: Callable, **bound: Any) -> Callable:
+    """``fn`` with ``bound`` fixed, under ``fn``'s name: jitted, it compiles
+    as ``jit_<fn>`` (a ``functools.partial`` compiles as ``jit__unknown``),
+    so a profile names the program it ran."""
+    def step(params, batch):
+        return fn(params, batch, **bound)
+    step.__name__ = step.__qualname__ = fn.__name__
+    return step
+
+
 def make_prefill_step(cfg: ArchConfig, shape: ShapeConfig, ctx: ShardCtx = ShardCtx()):
     chunk = attn_chunk(shape.seq_len)
-    if is_encdec(cfg):
-        return functools.partial(encdec.encdec_prefill, cfg=cfg, ctx=ctx, chunk=chunk)
-    return functools.partial(lm.lm_prefill, cfg=cfg, ctx=ctx, chunk=chunk)
+    prefill = encdec.encdec_prefill if is_encdec(cfg) else lm.lm_prefill
+    return _named(prefill, cfg=cfg, ctx=ctx, chunk=chunk)
 
 
 def make_decode_step(cfg: ArchConfig, ctx: ShardCtx = ShardCtx()):
-    if is_encdec(cfg):
-        return functools.partial(encdec.encdec_decode, cfg=cfg, ctx=ctx)
-    return functools.partial(lm.lm_decode, cfg=cfg, ctx=ctx)
+    decode = encdec.encdec_decode if is_encdec(cfg) else lm.lm_decode
+    return _named(decode, cfg=cfg, ctx=ctx)
 
 
 def make_step(cfg: ArchConfig, shape: ShapeConfig, ctx: ShardCtx = ShardCtx()):

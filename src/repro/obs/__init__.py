@@ -28,8 +28,10 @@ One ``Observability`` object per serving process, threaded through
 **Overhead contract**: obs is opt-in and ``obs=None`` (the default
 everywhere) is a no-op stub path — consumers hold ``trace = obs.trace if
 obs else None`` and guard each hook with one ``is not None`` test, so the
-disabled path allocates no span objects and performs no metric work
-(asserted by ``tests/test_obs.py``); the enabled path must cost <= 5% of
+disabled path allocates no ring span and performs no metric work
+(asserted by ``tests/test_obs.py``; the profiler spans of
+``obs.trace.span`` are switched by the profiler, not by obs, and cost one
+annotation object each while it is off); the enabled path must cost <= 5% of
 ``bench_serve_batch`` requests/sec (asserted as an ERROR row, measured
 overhead recorded in ``BENCH_serve.json``).  Analysis is snapshot-time
 only — ``CriticalPathAnalyzer`` reads the ring lazily and adds nothing to

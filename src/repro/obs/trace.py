@@ -43,15 +43,27 @@ which includes every parity phase and the per-request promote/payload
 segments the critical-path analyzer consumes) are *always* recorded, so
 ``parity_digest()`` and ``obs.analyze`` attribution are byte-identical at
 any sampling rate; only the how-was-it-executed volume thins out.
+
+**Profiler spans** (``span()``): the program's layer boundaries
+(``router.*``, ``serve.*``, ``payload.*``) open a
+``jax.profiler.TraceAnnotation`` under a constant name, so host work lands
+on the profiler's timeline beside the device's programs.  The profiler is
+the switch: with it off a span costs the annotation object and a flag
+check — no string is formatted, and a request id is attached as metadata
+(never in the name) only while it is on.  Where the caller holds a
+``TraceBuffer``, the same scope also records the ring span, so ring and
+profiler hold one interval.  JAX is imported on the first span, so this
+module imports without it.
 """
 
 from __future__ import annotations
 
 import json
+import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Any, ContextManager, Deque, Dict, List, Optional, Tuple
 
-__all__ = ["PARITY_PHASES", "TraceBuffer"]
+__all__ = ["PARITY_PHASES", "TraceBuffer", "span"]
 
 PARITY_PHASES = ("request", "dispatch", "transfer")
 
@@ -212,3 +224,91 @@ class TraceBuffer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return len(doc["traceEvents"])
+
+
+# -------------------------------------------------------------- profiler spans
+class _NoAnnotation:
+    """Stand-in for ``TraceAnnotation`` where JAX is not installed."""
+
+    __slots__ = ()
+
+    def __init__(self, name: str, **metadata: Any) -> None:
+        pass
+
+    def __enter__(self) -> "_NoAnnotation":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        pass
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+
+def _resolve_annotation() -> Any:
+    """The annotation class, found on the first span."""
+    global _Annotation
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        TraceAnnotation = _NoAnnotation
+    _Annotation = TraceAnnotation
+    return TraceAnnotation
+
+
+_Annotation: Any = None
+
+
+class _RingSpan:
+    """A profiler span that also records its ring span on a normal exit.
+    ``detail`` may be set inside the scope; set to None, the ring records
+    nothing."""
+
+    __slots__ = ("_ann", "_ring", "_rid", "_name", "_phase", "_replica",
+                 "_parent", "detail", "_t0")
+
+    def __init__(self, ann: Any, ring: TraceBuffer, request_id: int,
+                 name: str, phase: str, replica: str, parent: str,
+                 detail: Optional[Tuple]) -> None:
+        self._ann = ann
+        self._ring = ring
+        self._rid = request_id
+        self._name = name
+        self._phase = phase
+        self._replica = replica
+        self._parent = parent
+        self.detail = detail
+
+    def __enter__(self) -> "_RingSpan":
+        self._ann.__enter__()
+        self._t0 = time.time()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        if self.detail is not None and exc[0] is None:
+            self._ring.record(self._rid, self._name, self._phase, self._t0,
+                              time.time(), self._replica, self._parent,
+                              self.detail)
+        self._ann.__exit__(*exc)
+
+
+def span(name: str, ring: Optional[TraceBuffer] = None,
+         request_id: int = -1, ring_name: str = "", phase: str = "",
+         replica: str = "", parent: str = "",
+         detail: Optional[Tuple] = ()) -> ContextManager:
+    """A scoped span named ``name`` (a constant) on the profiler's timeline.
+
+    With ``ring`` it also records ``(request_id, ring_name, phase, ...)``
+    into that buffer over the same scope (``TraceBuffer.record``'s fields);
+    without one it is the bare annotation.  ``request_id >= 0`` rides along
+    as annotation metadata while the profiler is on."""
+    cls = _Annotation if _Annotation is not None else _resolve_annotation()
+    if request_id >= 0 and cls.is_enabled():
+        ann = cls(name, request_id=request_id)
+    else:
+        ann = cls(name)
+    if ring is None:
+        return ann
+    return _RingSpan(ann, ring, request_id, ring_name, phase, replica,
+                     parent, detail)

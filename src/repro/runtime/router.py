@@ -35,6 +35,7 @@ from ..diffusion.tiers import TieredStore, TierSpec, default_tier_weights
 from ..diffusion.transfer import TransferEngine
 from ..index.warmstart import WarmStartReport, WarmStartStats, clone_hottest
 from ..obs.registry import P2Quantile
+from ..obs.trace import span
 from .admission import AdmissionController, AdmissionVerdict
 from .chaos import FaultStats
 from .fault_tolerance import HeartbeatMonitor
@@ -751,28 +752,30 @@ class CacheAffinityRouter:
         router.  ``REJECTED`` requests are refused at the edge — counted on
         the tenant's account and traced as a ``shed`` span, never silently
         dropped."""
-        now = time.monotonic() if now is None else now
-        if request.submit_time_s == 0.0:
-            request.submit_time_s = now
-        verdict = AdmissionVerdict.ACCEPTED
-        if self.admission is not None:
-            verdict = self.admission.on_submit(request, now)
-            if verdict is AdmissionVerdict.REJECTED:
-                self._shed_span(request, now, "rejected")
-                return verdict
-        self._requests[request.request_id] = request
-        if verdict is AdmissionVerdict.ACCEPTED:
-            self.dispatcher.submit(request)
-        # DEGRADED: admitted into the controller's bounded tenant queue;
-        # tick()'s admission pump releases it by credit share (or sheds it).
-        if self.drp is not None:
-            depth = self.dispatcher.queue_length()
+        with span("router.enqueue"):
+            now = time.monotonic() if now is None else now
+            if request.submit_time_s == 0.0:
+                request.submit_time_s = now
+            verdict = AdmissionVerdict.ACCEPTED
             if self.admission is not None:
-                depth += self.admission.queue_depth()
-            req = self.drp.on_queue_change(now, depth)
-            if req is not None:
-                self._pending_provisions.append(req)
-        return verdict
+                verdict = self.admission.on_submit(request, now)
+                if verdict is AdmissionVerdict.REJECTED:
+                    self._shed_span(request, now, "rejected")
+                    return verdict
+            self._requests[request.request_id] = request
+            if verdict is AdmissionVerdict.ACCEPTED:
+                self.dispatcher.submit(request)
+            # DEGRADED: admitted into the controller's bounded tenant
+            # queue; tick()'s admission pump releases it by credit share
+            # (or sheds it).
+            if self.drp is not None:
+                depth = self.dispatcher.queue_length()
+                if self.admission is not None:
+                    depth += self.admission.queue_depth()
+                req = self.drp.on_queue_change(now, depth)
+                if req is not None:
+                    self._pending_provisions.append(req)
+            return verdict
 
     def submit(self, request: RoutedRequest, now: Optional[float] = None) -> List[Assignment]:
         """Enqueue a request; returns any assignments routable right away."""
@@ -791,25 +794,26 @@ class CacheAffinityRouter:
     # ----------------------------------------------------------- main pump
     def tick(self, now: Optional[float] = None) -> List[Assignment]:
         """Drive elasticity + phase-1 routing; returns new assignments."""
-        now = time.monotonic() if now is None else now
-        if self.engine is not None:
-            self.engine.drain(now)      # release bandwidth of landed copies
-        if self._corrupt_refetch:
-            self._drain_corrupt_refetch(now)
-        self._complete_provisions(now)
-        if self.admission is not None:
-            self._admission_pump(now)
-        self._maybe_release(now)
-        out = self._drain_notify(now)
-        if self._perf is not None:
-            # Pool-utilization sample for the live resource integral
-            # (perf.resource_hours / perf.utilization), taken *after* the
-            # drain so the burst just assigned counts: non-free replicas
-            # (BUSY or PENDING-notified) are in use.
-            n = self.dispatcher.registered()
-            self._perf.on_sample(now, float(n),
-                                 float(n - self.dispatcher.free_count()))
-        return out
+        with span("router.tick"):
+            now = time.monotonic() if now is None else now
+            if self.engine is not None:
+                self.engine.drain(now)  # release bandwidth of landed copies
+            if self._corrupt_refetch:
+                self._drain_corrupt_refetch(now)
+            self._complete_provisions(now)
+            if self.admission is not None:
+                self._admission_pump(now)
+            self._maybe_release(now)
+            out = self._drain_notify(now)
+            if self._perf is not None:
+                # Pool-utilization sample for the live resource integral
+                # (perf.resource_hours / perf.utilization), taken *after* the
+                # drain so the burst just assigned counts: non-free replicas
+                # (BUSY or PENDING-notified) are in use.
+                n = self.dispatcher.registered()
+                self._perf.on_sample(now, float(n),
+                                     float(n - self.dispatcher.free_count()))
+            return out
 
     def _shed_span(self, request: RoutedRequest, now: float,
                    reason: str) -> None:
@@ -1256,14 +1260,15 @@ class CacheAffinityRouter:
 
     def complete(self, request: RoutedRequest, now: Optional[float] = None) -> List[Assignment]:
         """Replica finished a request: free it and run the pickup path."""
-        now = time.monotonic() if now is None else now
-        replica = self._finish(request, now)
-        assignments = self.tick(now)
-        if replica is not None:
-            picked = self._pickup(replica, now)
-            if picked is not None:
-                assignments.append(picked)
-        return assignments
+        with span("router.complete"):
+            now = time.monotonic() if now is None else now
+            replica = self._finish(request, now)
+            assignments = self.tick(now)
+            if replica is not None:
+                picked = self._pickup(replica, now)
+                if picked is not None:
+                    assignments.append(picked)
+            return assignments
 
     def complete_batch(self, requests: Sequence[RoutedRequest],
                        now: Optional[float] = None) -> List[Assignment]:
@@ -1280,15 +1285,16 @@ class CacheAffinityRouter:
         interleaving (the batch-plane contract; bench_serve_batch asserts
         it on its seeded streams).
         """
-        now = time.monotonic() if now is None else now
-        freed = [r for r in (self._finish(req, now) for req in requests)
-                 if r is not None]
-        assignments = self.tick(now)
-        for replica in freed:
-            picked = self._pickup(replica, now)
-            if picked is not None:
-                assignments.append(picked)
-        return assignments
+        with span("router.complete"):
+            now = time.monotonic() if now is None else now
+            freed = [r for r in (self._finish(req, now) for req in requests)
+                     if r is not None]
+            assignments = self.tick(now)
+            for replica in freed:
+                picked = self._pickup(replica, now)
+                if picked is not None:
+                    assignments.append(picked)
+            return assignments
 
     # ----------------------------------------------------------- elasticity
     def _complete_provisions(self, now: float) -> None:

@@ -32,6 +32,7 @@ from ..diffusion.payload import MeasuredBandwidth, RealPayload
 from ..diffusion.tiers import TierSpec
 from ..models import cache_init, init_params, make_decode_step, make_prefill_step
 from ..models.sharding import ShardCtx
+from ..obs.trace import span
 from .router import (Assignment, AdmissionController, CacheAffinityRouter,
                      RoutedRequest)
 
@@ -91,20 +92,15 @@ class ServeStats:
     prefills: int = 0
     decode_steps: int = 0
     restore_time_s: float = 0.0     # tier swap-in / transfer cost charged
-    response_times: List[float] = field(default_factory=list)
 
     @property
     def hit_rate(self) -> float:
         return self.prefix_hits / self.served if self.served else 0.0
 
-    @property
-    def avg_response_s(self) -> float:
-        return float(np.mean(self.response_times)) if self.response_times else 0.0
-
     def snapshot(self) -> Dict[str, float]:
         """Registry-source view (prefixed ``serve.`` when adopted)."""
         from ..obs.registry import stats_snapshot
-        return stats_snapshot(self, props=("hit_rate", "avg_response_s"))
+        return stats_snapshot(self, props=("hit_rate",))
 
 
 def session_object(sid: str) -> str:
@@ -348,6 +344,10 @@ class DiffusionServer:
 
     # ------------------------------------------------------------- serve
     def _run_request(self, replica: Replica, routed: RoutedRequest) -> None:
+        with span("serve.request", request_id=routed.request_id):
+            self._serve(replica, routed)
+
+    def _serve(self, replica: Replica, routed: RoutedRequest) -> None:
         req: Request = routed.payload
         req.replica = replica.name
         sid = req.session_id
@@ -361,6 +361,7 @@ class DiffusionServer:
         backend = (store.tiers.payload
                    if self.payload_mode == "real" and store is not None
                    else None)
+        trace = self._trace
         caches = None
         if routed.hits and state is not None:
             # Charge restore by the tier the prefix was found in: an HBM hit
@@ -369,68 +370,68 @@ class DiffusionServer:
             found = routed.sources.get(obj)
             swapped = (store is not None and found is not None
                        and found != store.top_tier)
-            t0 = time.time()
-            # payload="real": the routing access already promoted a demoted
-            # prefix, which made the backend device_put the host copy back
-            # onto this replica's device (timed into self.measured).  None
-            # means a poisoned spill copy was dropped: replay the prompt.
-            caches = state["caches"] if backend is None else backend.value(obj)
-            if caches is not None:
-                req.prefix_hit = True
-                self.stats.prefix_hits += 1
-                pos = state["pos"]
-                if swapped:
-                    self.stats.swap_ins += 1
-                    if backend is not None and self._trace is not None:
-                        # Structural span: the real KV bytes returning to
-                        # the device for this request.
-                        self._trace.record(
-                            routed.request_id, obj, "payload", t0,
-                            time.time(), replica=replica.name,
-                            parent="dispatch", detail=(found, store.top_tier))
-                self.stats.restore_time_s += routed.restore_cost_s
+            # Ring: a structural span for the real KV bytes returning to
+            # the device for this request (swap-ins only).
+            with span("serve.restore", trace, routed.request_id, obj,
+                      "payload", replica.name, "dispatch") as sp:
+                # payload="real": the routing access already promoted a
+                # demoted prefix, which made the backend device_put the
+                # host copy back onto this replica's device (timed into
+                # self.measured).  None means a poisoned spill copy was
+                # dropped: replay the prompt.
+                caches = (state["caches"] if backend is None
+                          else backend.value(obj))
+                if caches is not None:
+                    req.prefix_hit = True
+                    self.stats.prefix_hits += 1
+                    pos = state["pos"]
+                    if swapped:
+                        self.stats.swap_ins += 1
+                    self.stats.restore_time_s += routed.restore_cost_s
+                if trace is not None:
+                    sp.detail = ((found, store.top_tier)
+                                 if swapped and backend is not None
+                                 and caches is not None else None)
         if caches is None:
             # "copy from persistent storage": replay the prompt (prefill).
             self.stats.prefills += 1
-            t0 = time.time()
-            prompt = jax.device_put(np.asarray(req.prompt, np.int32)[None, :],
-                                    replica.device)
-            _, pre_caches = self.prefill_fn(replica.params, {"tokens": prompt})
-            # prefill caches are full-seq; re-home into a decode cache buffer
-            with jax.default_device(replica.device):
-                caches = cache_init(self.cfg, 1, self.cap)
-            caches = _merge_prefill_caches(caches, pre_caches, self.cfg)
+            # Ring: compute phases are not attribution segments for the
+            # critical-path analyzer (they land in "service" by
+            # construction), but the span makes the prefill-vs-decode split
+            # visible in the trace exports.
+            with span("serve.prefill", trace, routed.request_id, "prefill",
+                      "compute", replica.name, "dispatch",
+                      (req.prompt.shape[0],)):
+                prompt = jax.device_put(
+                    np.asarray(req.prompt, np.int32)[None, :], replica.device)
+                _, pre_caches = self.prefill_fn(replica.params,
+                                                {"tokens": prompt})
+                # prefill caches are full-seq; re-home into a decode cache
+                # buffer
+                with jax.default_device(replica.device):
+                    caches = cache_init(self.cfg, 1, self.cap)
+                caches = _merge_prefill_caches(caches, pre_caches, self.cfg)
             pos = req.prompt.shape[0]
-            if self._trace is not None:
-                # Segment timestamp for the critical-path analyzer: compute
-                # phases are not attribution segments (they land in
-                # "service" by construction), but the span makes the
-                # prefill-vs-decode split visible in the trace exports.
-                self._trace.record(routed.request_id, "prefill", "compute",
-                                   t0, time.time(), replica=replica.name,
-                                   parent="dispatch",
-                                   detail=(req.prompt.shape[0],))
 
-        t0 = time.time()
-        token = jax.device_put(
-            np.asarray([int(req.prompt[-1]) % self.cfg.vocab_size], np.int32),
-            replica.device)
-        for _ in range(req.max_new_tokens):
-            if pos >= self.cap - 1:
-                break
-            logits, caches = self.decode_fn(
-                replica.params, {"token": token, "pos": np.int32(pos),
-                                 "caches": caches}
-            )
-            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            req.generated.append(token)
-            req.last_logits = logits
-            pos += 1
-            self.stats.decode_steps += 1
-        if self._trace is not None:
-            self._trace.record(routed.request_id, "decode", "compute",
-                               t0, time.time(), replica=replica.name,
-                               parent="dispatch", detail=(pos,))
+        with span("serve.decode", trace, routed.request_id, "decode",
+                  "compute", replica.name, "dispatch") as sp:
+            token = jax.device_put(
+                np.asarray([int(req.prompt[-1]) % self.cfg.vocab_size],
+                           np.int32), replica.device)
+            for _ in range(req.max_new_tokens):
+                if pos >= self.cap - 1:
+                    break
+                with span("serve.token"):
+                    logits, caches = self.decode_fn(
+                        replica.params, {"token": token, "pos": np.int32(pos),
+                                         "caches": caches})
+                    token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                req.generated.append(token)
+                req.last_logits = logits
+                pos += 1
+                self.stats.decode_steps += 1
+            if trace is not None:
+                sp.detail = (pos,)
         if use_cache:
             # keep the KV payload iff the router's store admitted the object
             # (first-available ships no location info and caches nothing;
@@ -450,7 +451,6 @@ class DiffusionServer:
                 replica.sessions.pop(sid, None)
         req.finish_time_s = time.time()
         self.stats.served += 1
-        self.stats.response_times.append(req.response_time_s)
 
     # -------------------------------------------------------------- chaos
     def chaos_tick(self, now: Optional[float] = None) -> List[str]:
@@ -489,6 +489,10 @@ class DiffusionServer:
 
     def step(self) -> int:
         """Execute routed work until queue and assignments drain. Returns served."""
+        with span("serve.step"):
+            return self._step()
+
+    def _step(self) -> int:
         served = 0
         idle_rounds = 0
         if self.chaos is not None or self.router.monitor is not None:
