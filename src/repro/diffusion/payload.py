@@ -9,7 +9,8 @@ drop) and moves the real tensors between physical homes:
   * ``hbm``  — accelerator device arrays (``jax.device_put``; every timed
     edge is closed with ``jax.block_until_ready`` so async dispatch cannot
     fake bandwidth);
-  * ``dram`` — host numpy (``jax.device_get`` on the way down);
+  * ``dram`` — host numpy (``np.asarray`` of the device arrays on the way
+    down);
   * ``disk`` — chunked spill files written through the checkpoint plane's
     dtype-safe byte view (``checkpoint.checkpointer.to_raw_bytes``), with a
     per-chunk sha256 verified on every read back.
@@ -31,8 +32,20 @@ an object whose payload was never put) degrades to bookkeeping-only, so the
 ``payload="modeled"`` and ``payload="real"`` engine modes make bit-identical
 promote/demote/fetch decisions (asserted in ``tests/test_payload.py``).
 
-``MeasuredBandwidth`` accumulates bytes/seconds per (src tier, dst tier)
-edge; ``check_roofline`` flags any edge whose *aggregate* measured bandwidth
+Timed and staged edges.  A ``put`` of device arrays that already sit on
+the backend's device into ``hbm`` registers those very arrays and starts
+their host copy (``copy_to_host_async``): the *host shadow*, which runs
+behind the computation that made them instead of on the request path.  A
+demotion out of ``hbm`` of such an object adopts the shadow (``np.asarray``
+returns the landed copy, or waits for the part still in flight) and is
+recorded as a *staged* move: its bytes and count, never its seconds, since
+no clock covered the copy.  Every other move is *timed* as before: swap-ins
+(dram->hbm), spills and reads back from disk, and demotions of objects put
+from host arrays or promoted since their last ``put``.
+
+``MeasuredBandwidth`` accumulates bytes/seconds of timed moves, and bytes of
+staged moves, per (src tier, dst tier) edge; ``check_roofline`` flags any
+edge whose *aggregate* timed bandwidth
 exceeds ``factor``x the roofline of its slower endpoint — the peaks of the
 accelerator the bytes moved on (``launch.rooflines``, by ``device_kind``),
 or the DES's modeled tier calibration for host-only runs — measured transfers
@@ -68,23 +81,37 @@ _ROOFLINE_TIERS = ("hbm", "dram", "disk")
 
 
 class MeasuredBandwidth:
-    """Per-(src, dst) accumulator of measured byte movement."""
+    """Per-(src, dst) accumulator of measured byte movement.
+
+    Timed moves (``record``) carry bytes and seconds; staged moves
+    (``record_staged``) adopted a copy that no clock covered and carry bytes
+    only.  ``bandwidth``, ``total_bytes`` and ``check_roofline`` read timed
+    moves alone; ``rows`` shows both."""
 
     def __init__(self) -> None:
-        # (src, dst) -> [bytes, seconds, moves]
+        # (src, dst) -> [bytes, seconds, moves, staged bytes, staged moves]
         self._acc: Dict[Tuple[str, str], List[float]] = {}
         # device_kind of the accelerator the bytes moved on (None: host-only
         # run, e.g. the CPU backend or FakePayload's modeled seconds)
         self.device_kind: Optional[str] = None
 
+    def _edge(self, src: str, dst: str) -> List[float]:
+        return self._acc.setdefault((src, dst), [0.0] * 5)
+
     def record(self, src: str, dst: str, nbytes: float, seconds: float) -> None:
-        ent = self._acc.setdefault((src, dst), [0.0, 0.0, 0.0])
+        ent = self._edge(src, dst)
         ent[0] += float(nbytes)
         ent[1] += max(0.0, float(seconds))
         ent[2] += 1.0
 
+    def record_staged(self, src: str, dst: str, nbytes: float) -> None:
+        """A move that adopted a copy staged earlier, off the clock."""
+        ent = self._edge(src, dst)
+        ent[3] += float(nbytes)
+        ent[4] += 1.0
+
     def bandwidth(self, src: str, dst: str) -> float:
-        """Aggregate bytes/s over every recorded move on the edge (0 if none)."""
+        """Aggregate bytes/s over every timed move on the edge (0 if none)."""
         ent = self._acc.get((src, dst))
         if ent is None or ent[1] <= 0.0:
             return 0.0
@@ -98,20 +125,20 @@ class MeasuredBandwidth:
         """Stable-sorted export rows for BENCH_* history entries."""
         out = []
         for (src, dst) in sorted(self._acc):
-            b, s, n = self._acc[(src, dst)]
+            b, s, n, sb, sn = self._acc[(src, dst)]
             out.append({
                 "src": src, "dst": dst, "bytes": b, "seconds": s,
                 "moves": int(n), "bytes_per_s": b / s if s > 0 else 0.0,
+                "staged_bytes": sb, "staged_moves": int(sn),
             })
         return out
 
     def merge(self, other: "MeasuredBandwidth") -> None:
         self.device_kind = self.device_kind or other.device_kind
-        for (src, dst), (b, s, n) in other._acc.items():
-            ent = self._acc.setdefault((src, dst), [0.0, 0.0, 0.0])
-            ent[0] += b
-            ent[1] += s
-            ent[2] += n
+        for edge, vals in other._acc.items():
+            ent = self._edge(*edge)
+            for i, v in enumerate(vals):
+                ent[i] += v
 
     def tier_roofline(self) -> Callable[[str], float]:
         """Tier -> peak bytes/s: the measured device's peaks (hbm: its HBM,
@@ -169,7 +196,25 @@ def _tree_rebuild(template: Any, leaves: List[Any]) -> Any:
 
 
 def _leaf_nbytes(leaves: List[Any]) -> float:
-    return float(sum(int(np.asarray(l).nbytes) for l in leaves))
+    # ``.nbytes`` of a device array comes from its shape: no transfer
+    return float(sum(int(l.nbytes if hasattr(l, "nbytes")
+                         else np.asarray(l).nbytes) for l in leaves))
+
+
+def _host_copy(leaf: Any) -> np.ndarray:
+    """``leaf``'s bytes in a host buffer of their own.
+
+    ``np.asarray`` of a device array waits for it and returns its host
+    copy: a distinct buffer on an accelerator, the landed shadow where one
+    was staged.  Of a host array, or of a device array on the CPU backend,
+    it *aliases* the source, which would make a demotion a free pointer
+    cast (and its measured bandwidth a lie); those are copied, so the new
+    home survives the old one being dropped."""
+    host = np.asarray(leaf)
+    devices = getattr(leaf, "devices", None)
+    if devices is None or any(d.platform == "cpu" for d in devices()):
+        host = np.array(host, copy=True)
+    return host
 
 
 class PayloadBackend:
@@ -298,11 +343,14 @@ class RealPayload(PayloadBackend):
 
     Every timed edge that touches the device is closed with
     ``jax.block_until_ready`` before the clock stops — the measured
-    bandwidth is the bytes actually landed, not the async dispatch.  jax is
-    imported lazily so modeled-only runs never pay for it.  Each move runs
-    in a ``payload.promote`` or ``payload.demote`` span by direction (disk
-    spills demote), each ``put`` and ``get`` in ``payload.put`` and
-    ``payload.get`` (``obs.trace.span``).
+    bandwidth is the bytes actually landed, not the async dispatch.  A
+    demotion out of hbm that adopts the host shadow staged at ``put`` is
+    recorded as staged, untimed (module docstring); ``demotions`` counts
+    moves out of hbm and ``staged_demotions`` those that adopted a shadow.
+    jax is imported lazily so modeled-only runs never pay for it.  Each
+    move runs in a ``payload.promote`` or ``payload.demote`` span by
+    direction (disk spills demote), each ``put`` and ``get`` in
+    ``payload.put`` and ``payload.get`` (``obs.trace.span``).
     """
 
     def __init__(
@@ -334,32 +382,35 @@ class RealPayload(PayloadBackend):
         # leaves: in-memory ndarray/device-array, or _SpilledLeaf on disk
         self._leaves: Dict[str, List[Any]] = {}
         self._nbytes: Dict[str, float] = {}
+        # hbm objects whose host shadow was started at ``put``
+        self._staged: set = set()
+        self.demotions = 0
+        self.staged_demotions = 0
         self._spill_seq = 0
 
     # -- physical homes -------------------------------------------------------
-    def _to_device(self, leaves: List[Any]) -> List[Any]:
+    def _target(self) -> Any:
         import jax
         device = self.device if self.device is not None else jax.devices()[0]
         if device.platform != "cpu":
             # bytes land on an accelerator: hold them to its own peaks
             self.measured.device_kind = device.device_kind
+        return device
+
+    def _to_device(self, leaves: List[Any]) -> List[Any]:
+        import jax
+        device = self._target()
         out = [jax.device_put(l, device) for l in leaves]
         return [jax.block_until_ready(l) for l in out]
 
     def _to_host(self, obj: str) -> List[np.ndarray]:
-        """Materialize the current home into contiguous host arrays.
-
-        Always a real copy: on the CPU backend ``np.asarray`` of a device
-        array *aliases* the device buffer, which would make a "demotion" a
-        free pointer cast (and its measured bandwidth a lie) — the DRAM
-        home must be a distinct host buffer that survives the device copy
+        """Materialize the current home into host arrays that own their
+        bytes (``_host_copy``): the DRAM home must survive the device copy
         being dropped."""
         leaves = self._leaves[obj]
         if leaves and isinstance(leaves[0], _SpilledLeaf):
             return [self._read_spilled(s) for s in leaves]
-        import jax
-        jax.block_until_ready(leaves)
-        return [np.array(np.asarray(l), copy=True) for l in leaves]
+        return [_host_copy(l) for l in leaves]
 
     def _spill(self, obj: str, host: List[np.ndarray]) -> List[_SpilledLeaf]:
         if self.spill_dir is None:
@@ -413,19 +464,35 @@ class RealPayload(PayloadBackend):
 
     # -- interface ------------------------------------------------------------
     def put(self, obj: str, value: Any, tier: str) -> None:
-        # On the device this waits for the computation that made ``value``.
+        # Device arrays already on this backend's device, put into hbm, are
+        # registered as they are: no wait for the computation that made
+        # them.  Their host shadow starts behind it, for a later demotion
+        # to adopt.  Host arrays, or arrays on another device, are copied to
+        # the device and waited for.
         with span("payload.put"):
             self.dropped(obj)           # re-put replaces (frees old spill)
             leaves: List[Any] = []
             template = _tree_leaves(value, leaves)
             self._nbytes[obj] = _leaf_nbytes(leaves)
             self._templates[obj] = template
-            if tier == "hbm":
+            if tier == "hbm" and self._on_target(leaves):
+                for leaf in leaves:
+                    leaf.copy_to_host_async()
+                self._leaves[obj] = leaves
+                self._staged.add(obj)
+            elif tier == "hbm":
                 self._leaves[obj] = self._to_device(leaves)
             else:
                 host = [np.ascontiguousarray(np.asarray(l)) for l in leaves]
                 self._leaves[obj] = self._home(obj, host, tier)
             self._tiers[obj] = tier
+
+    def _on_target(self, leaves: List[Any]) -> bool:
+        """Every leaf a device array on this backend's device."""
+        import jax
+        device = self._target()
+        return all(isinstance(l, jax.Array) and l.devices() == {device}
+                   for l in leaves)
 
     def _recover_corrupt(self, obj: str) -> None:
         """Poisoned spill copy: drop it (remaining chunks freed), notify the
@@ -483,6 +550,7 @@ class RealPayload(PayloadBackend):
             return
         old = self._leaves[obj]
         up = _TIER_RANK.get(tier, 1) < _TIER_RANK.get(src, 1)
+        staged = obj in self._staged    # in hbm, its shadow started at put
         with span("payload.promote" if up else "payload.demote"):
             t0 = time.perf_counter()
             try:
@@ -496,7 +564,14 @@ class RealPayload(PayloadBackend):
             dt = time.perf_counter() - t0
             self._free_spill(old)
         self._tiers[obj] = tier
-        self.measured.record(src, tier, self._nbytes[obj], dt)
+        self._staged.discard(obj)
+        if src == "hbm":
+            self.demotions += 1
+        if staged:
+            self.staged_demotions += 1
+            self.measured.record_staged(src, tier, self._nbytes[obj])
+        else:
+            self.measured.record(src, tier, self._nbytes[obj], dt)
 
     def dropped(self, obj: str) -> None:
         leaves = self._leaves.pop(obj, None)
@@ -505,3 +580,4 @@ class RealPayload(PayloadBackend):
         self._tiers.pop(obj, None)
         self._templates.pop(obj, None)
         self._nbytes.pop(obj, None)
+        self._staged.discard(obj)

@@ -147,7 +147,9 @@ def main() -> None:
                                    tag=f"r{served:06d}")
     s, r = srv.stats, srv.router.stats
     print(f"served={s.served} prefix_hit={s.hit_rate:.0%} prefills={s.prefills} "
-          f"swap_ins={s.swap_ins} decode_steps={s.decode_steps} "
+          f"swap_ins={s.swap_ins} "
+          f"staged_demotions={srv.staged_demotion_share():.0%} "
+          f"decode_steps={s.decode_steps} "
           f"replicas={len(srv.replicas)} scale_ups={r.scale_ups} "
           # window-only percentiles (exact over the latency reservoir's
           # most recent samples, blind to older ones) — labeled as such.

@@ -220,6 +220,7 @@ def serve_smoke(cfg: ArchConfig, *, seed: int = 0, replicas: int = 2,
         f"compile_s={clock.seconds:.3f} over {clock.count} programs")
     log(f"serve: submitted={len(reqs)} served={s.served} lost={lost} "
         f"prefix_hits={s.prefix_hits} swap_ins={s.swap_ins} "
+        f"staged_demotions={srv.staged_demotion_share():.0%} "
         f"prefills={s.prefills} decode_steps={s.decode_steps} "
         f"replicas={len(srv.replicas)}")
     log("smoke timings (per-request wall ms, compile included in the first "

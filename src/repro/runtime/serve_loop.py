@@ -304,6 +304,15 @@ class DiffusionServer:
         """Measured dram->hbm swap-in bytes/s (0.0 until one happened)."""
         return self.measured.bandwidth("dram", "hbm")
 
+    def staged_demotion_share(self) -> float:
+        """Share of the live stores' HBM demotions that adopted the host
+        copy staged when the session's turn ended (0.0 until one
+        happened)."""
+        backends = [s.tiers.payload for s in self.router.stores.values()]
+        demoted = sum(getattr(b, "demotions", 0) for b in backends)
+        staged = sum(getattr(b, "staged_demotions", 0) for b in backends)
+        return staged / demoted if demoted else 0.0
+
     # ------------------------------------------------------------ submit
     def tenant_of_session(self, session_id: str) -> str:
         """Stable session → tenant assignment ("" when single-tenant):

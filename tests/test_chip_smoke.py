@@ -40,6 +40,8 @@ def test_serve_smoke_phase_passes_on_cpu():
     assert any(l.startswith("reference:") and l.endswith("passed")
                for l in lines)
     assert any(l.startswith("smoke timings") for l in lines)
+    assert any(l.startswith("serve:") and "staged_demotions=" in l
+               for l in lines)
 
 
 def test_reference_check_catches_a_wrong_swap_in(monkeypatch):

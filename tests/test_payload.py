@@ -343,3 +343,191 @@ class TestRealPayloadRoundTrip:
         assert real.measured.total_bytes > 0
         assert real.measured.check_roofline(factor=10.0) == []
         assert modeled.measured.total_bytes == 0
+
+
+# ------------------------------------------- host shadow staged at put
+
+class _CountingNumpy:
+    """``numpy`` for the payload module, counting conversions of device
+    arrays to host arrays."""
+
+    def __init__(self):
+        self.conversions = 0
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def _counted(self, fn, a, *args, **kwargs):
+        import jax
+        if isinstance(a, jax.Array):
+            self.conversions += 1
+        return fn(a, *args, **kwargs)
+
+    def asarray(self, a, *args, **kwargs):
+        return self._counted(np.asarray, a, *args, **kwargs)
+
+    def array(self, a, *args, **kwargs):
+        return self._counted(np.array, a, *args, **kwargs)
+
+    def ascontiguousarray(self, a, *args, **kwargs):
+        return self._counted(np.ascontiguousarray, a, *args, **kwargs)
+
+
+def device_kv(seed: int) -> dict:
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    return {
+        "k": jnp.asarray(rng.standard_normal((2, 8, 16)), jnp.bfloat16),
+        "v": [jnp.asarray(rng.standard_normal((2, 8, 16)), jnp.bfloat16),
+              jnp.asarray(rng.integers(0, 100, size=8), jnp.int32)],
+    }
+
+
+def host_of(tree: dict) -> dict:
+    return {"k": np.array(tree["k"]),
+            "v": [np.array(tree["v"][0]), np.array(tree["v"][1])]}
+
+
+class TestStagedDemotion:
+    def test_put_of_device_arrays_registers_them_without_a_host_copy(
+            self, monkeypatch):
+        import jax
+        from jax._src.array import ArrayImpl
+        from repro.diffusion import payload
+        counting = _CountingNumpy()
+        waits = []
+        dunder = []
+        puts = []
+        array_fn = ArrayImpl.__array__
+        monkeypatch.setattr(payload, "np", counting)
+        monkeypatch.setattr(
+            ArrayImpl, "__array__",
+            lambda self, *a, **k: dunder.append(1) or array_fn(self, *a, **k))
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: waits.append(x) or x)
+        monkeypatch.setattr(jax, "device_put",
+                            lambda *a, **k: puts.append(a))
+        tree = device_kv(0)
+        p = payload.RealPayload("t")
+        p.put("kv:a", tree, "hbm")
+        assert counting.conversions == 0 and not dunder
+        assert not waits and not puts
+        held = p.value("kv:a")
+        assert held["k"] is tree["k"] and held["v"][1] is tree["v"][1]
+        assert p.nbytes("kv:a") == sum(
+            l.nbytes for l in (tree["k"], *tree["v"]))
+
+    def test_demotion_adopts_the_shadow_as_a_staged_move(self):
+        tree = device_kv(1)
+        host0 = host_of(tree)
+        from repro.diffusion.payload import RealPayload
+        p = RealPayload("t")
+        p.put("kv:a", tree, "hbm")
+        p.moved("kv:a", "dram")
+        assert (p.demotions, p.staged_demotions) == (1, 1)
+        home = p.value("kv:a")          # the DRAM home's own arrays
+        for got, leaf in ((home["k"], tree["k"]),
+                          (home["v"][0], tree["v"][0])):
+            assert not np.shares_memory(got, np.asarray(leaf))
+        for leaf in (tree["k"], *tree["v"]):
+            leaf.delete()               # the DRAM home owns its bytes
+        assert tree_equal(p.get("kv:a"), host0)
+        (row,) = p.measured.rows()
+        assert (row["src"], row["dst"]) == ("hbm", "dram")
+        assert row["staged_bytes"] == p.nbytes("kv:a") > 0
+        assert row["staged_moves"] == 1
+        assert row["bytes"] == row["seconds"] == row["moves"] == 0
+        assert p.measured.total_bytes == 0.0
+        assert p.measured.check_roofline() == []
+
+    def test_demotion_after_a_promotion_is_timed(self):
+        """Promoted bytes were not staged: their next demotion copies and
+        is timed as before."""
+        from repro.diffusion.payload import RealPayload
+        tree = device_kv(2)
+        host0 = host_of(tree)
+        p = RealPayload("t")
+        p.put("kv:a", tree, "hbm")
+        for tier in ("dram", "hbm", "dram"):
+            p.moved("kv:a", tier)
+        assert (p.demotions, p.staged_demotions) == (2, 1)
+        edge = {(r["src"], r["dst"]): r for r in p.measured.rows()}
+        assert edge[("hbm", "dram")]["moves"] == 1
+        assert edge[("hbm", "dram")]["staged_moves"] == 1
+        assert edge[("dram", "hbm")]["moves"] == 1
+        assert tree_equal(p.get("kv:a"), host0)
+
+    def test_host_array_put_demotes_as_a_timed_move(self):
+        from repro.diffusion.payload import RealPayload
+        tree = kv_tree(3)
+        p = RealPayload("t")
+        p.put("kv:a", tree, "hbm")
+        p.moved("kv:a", "dram")
+        assert (p.demotions, p.staged_demotions) == (1, 0)
+        (row,) = p.measured.rows()
+        assert row["moves"] == 1 and row["bytes"] == p.nbytes("kv:a") > 0
+        assert row["seconds"] > 0
+        assert row["staged_moves"] == 0 and row["staged_bytes"] == 0.0
+        assert tree_equal(p.get("kv:a"), tree)
+
+    def test_peer_get_of_a_staged_object_is_byte_equal(self):
+        from repro.diffusion.payload import RealPayload
+        tree = device_kv(4)
+        host0 = host_of(tree)
+        p = RealPayload("t")
+        p.put("kv:a", tree, "hbm")
+        got = p.get("kv:a")
+        assert tree_equal(got, host0)
+        assert all(isinstance(l, np.ndarray)
+                   for l in (got["k"], *got["v"]))
+        assert p.tier_of("kv:a") == "hbm" and p.measured.rows() == []
+        q = RealPayload("peer")             # the fetching replica's copy
+        q.put("kv:a", got, "dram")
+        assert tree_equal(q.get("kv:a"), host0)
+
+    def test_staged_moves_sit_beside_timed_ones(self):
+        m = MeasuredBandwidth()
+        m.record_staged("hbm", "dram", 64.0)
+        assert m.bandwidth("hbm", "dram") == 0.0 and m.total_bytes == 0.0
+        assert m.check_roofline() == []
+        m.record("hbm", "dram", 10.0, 1.0)
+        other = MeasuredBandwidth()
+        other.record_staged("hbm", "dram", 36.0)
+        m.merge(other)
+        (row,) = m.rows()
+        assert (row["bytes"], row["seconds"], row["moves"]) == (10.0, 1.0, 1)
+        assert (row["staged_bytes"], row["staged_moves"]) == (100.0, 2)
+        assert m.bandwidth("hbm", "dram") == 10.0
+
+    def test_server_demotions_adopt_staged_copies(self):
+        """The reduced serving loop: HBM evictions of sessions put at the
+        end of their turn adopt the staged copy, and every token matches
+        the modeled run, whose KV never leaves the device."""
+        from repro.configs import get_arch
+        from repro.runtime.serve_loop import DiffusionServer
+
+        cfg = get_arch("internlm2-1.8b").reduced()
+        rng = np.random.default_rng(0)
+        prompts = {f"s{i}": rng.integers(0, cfg.vocab_size, size=(12,))
+                   for i in range(3)}
+
+        def run(payload):
+            srv = DiffusionServer(cfg, policy="good-cache-compute",
+                                  max_replicas=1, min_replicas=1,
+                                  cache_cap=48, max_sessions=2,
+                                  host_cache_sessions=4, seed=1,
+                                  payload=payload)
+            reqs = []
+            for _ in range(2):
+                reqs += [srv.submit(sid, p, max_new_tokens=2)
+                         for sid, p in prompts.items()]
+                srv.step()
+            return srv, [[int(t[0]) for t in r.generated] for r in reqs]
+
+        (real, real_tokens), (modeled, modeled_tokens) = (
+            run("real"), run("modeled"))
+        assert real.stats.swap_ins >= 1
+        assert real.staged_demotion_share() > 0.0
+        assert modeled.staged_demotion_share() == 0.0
+        assert real_tokens == modeled_tokens
+        assert real.measured.check_roofline() == []
