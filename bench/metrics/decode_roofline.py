@@ -1,8 +1,9 @@
 """The decode program's share of its roofline, in %: the least time the
-chip needs for a decode call (``bench/work.py``: the weights the token
-needs, read once, and the keys and values it attends, against the peaks in
-``bench/peaks.json``), averaged over the window's decode steps, over the
-device time per call that the trace measured."""
+chip needs for a decode call (the configuration's work counts,
+``bench/work.py`` by default: the weights the token needs, read once, and
+the keys and values it attends, against the peaks in ``bench/peaks.json``),
+averaged over the window's decode steps, over the device time per call
+that the trace measured."""
 
 import work
 
@@ -12,6 +13,7 @@ def read(run):
     contexts = run.decode_contexts()
     if ms is None or not contexts:
         return None
-    need = [work.needed_seconds(*work.decode_call(run.cfg, [c]), run.peak)[0]
+    need = [work.needed_seconds(*run.work.decode_call(run.cfg, [c]),
+                                run.peak)[0]
             for c in contexts]
     return 100.0 * (sum(need) / len(need)) / (ms * 1e-3)

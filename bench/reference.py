@@ -13,13 +13,31 @@ are widened to float32 at once.
 ``mode="fp8"`` is the control: the same forward with both operands of every
 product rounded to float8 (e4m3, one absmax scale per tensor), the step
 below the bf16 the configurations serve in.
+
+A reference module.  A configuration file names the module that holds its
+model's forward (``"reference": "<path under bench/ without .py>"``; this
+file where it names none), and the harness loads it by path.  Such a module
+provides:
+
+* ``FIELDS``: the program configuration's attributes it reads.  The harness
+  hands them to ``hidden`` as the dict ``m``, with the file's
+  ``semantics`` added;
+* ``hidden(params, tokens, m, mode)``: the final-normed hidden states
+  ``[B, S, D]`` in float32 of a cache-free causal forward over ``tokens``
+  ``[B, S]``, with every product taken from ``reference._products(mode)``,
+  so that the fp8 control rounds its products too.  ``block`` below is one
+  layer of the uniform stack, for a module whose stack mixes it with others.
+
+What a module shares stays here, in one copy: the seeded weights
+(``make_weights``, ``seed_key``), the head (``_logits``) and the served
+tokens' gaps (``served_gaps``, which runs the module's ``hidden``).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +66,8 @@ def _leaf_scale(path: Tuple[str, ...], shape: Tuple[int, ...]) -> float:
 def make_weights(shapes: Any, seed: int) -> Any:
     """Random weights for a tree of ``ShapeDtypeStruct``s, in one jitted
     call on the default device: normal over sqrt(fan-in) for matrices,
-    1 + N(0, 0.1) for norm scales, each leaf in its own dtype."""
+    1 + N(0, 0.1) for norm scales, N(0, 0.1) for other vectors (a bias),
+    each leaf in its own dtype."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     paths = [tuple(str(getattr(k, "key", getattr(k, "idx", k))) for k in p)
              for p, _ in flat]
@@ -61,6 +80,8 @@ def make_weights(shapes: Any, seed: int) -> Any:
             z = jax.random.normal(k, s.shape, F32)
             if path[-1] == "scale":
                 out.append((1.0 + 0.1 * z).astype(s.dtype))
+            elif len(s.shape) == 1:
+                out.append((0.1 * z).astype(s.dtype))
             else:
                 out.append((z * _leaf_scale(path, s.shape)).astype(s.dtype))
         return out
@@ -144,28 +165,35 @@ def _moe(lp, x, m, mm):
     return out.reshape(b, s, d)
 
 
+# The program configuration's attributes that ``hidden`` reads.
+FIELDS = ("d_model", "num_heads", "num_kv_heads", "head_dim", "vocab_size",
+          "rope_theta", "norm_eps", "moe_top_k")
+
+
+def block(x, lp, m: Dict[str, Any], mm, es):
+    """One pre-norm layer of the stack: attention, then a SwiGLU FFN or a
+    mixture of experts; ``mm, es`` are ``_products(mode)``."""
+    eps = m["norm_eps"]
+    a = _rmsnorm(x, lp["norm1"]["scale"].astype(F32), eps)
+    x = x + _attention(lp["attn"], a, m, mm, es)
+    f = _rmsnorm(x, lp["norm2"]["scale"].astype(F32), eps)
+    if "moe" in lp:
+        return x + _moe(lp["moe"], f, m, mm)
+    w = {k: v.astype(F32) for k, v in lp["ffn"].items()}
+    return x + _swiglu(f, w["w_gate"], w["w_up"], w["w_down"], mm)
+
+
 def hidden(params, tokens, m: Dict[str, Any], mode: str = "f32"):
     """Final-normed hidden states [B, S, D] of a cache-free causal forward
     over ``tokens`` [B, S]."""
     if params["rem"] or set(params["groups"]) != {"b0"}:
         raise ValueError("reference: only uniform attention stacks")
     mm, es = _products(mode)
-    eps = m["norm_eps"]
     x = params["embed"][tokens].astype(F32)
-
-    def layer(x, lp):
-        a = _rmsnorm(x, lp["norm1"]["scale"].astype(F32), eps)
-        x = x + _attention(lp["attn"], a, m, mm, es)
-        f = _rmsnorm(x, lp["norm2"]["scale"].astype(F32), eps)
-        if "moe" in lp:
-            x = x + _moe(lp["moe"], f, m, mm)
-        else:
-            w = {k: v.astype(F32) for k, v in lp["ffn"].items()}
-            x = x + _swiglu(f, w["w_gate"], w["w_up"], w["w_down"], mm)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, params["groups"]["b0"])
-    return _rmsnorm(x, params["final_norm"]["scale"].astype(F32), eps)
+    x, _ = jax.lax.scan(lambda x, lp: (block(x, lp, m, mm, es), None), x,
+                        params["groups"]["b0"])
+    return _rmsnorm(x, params["final_norm"]["scale"].astype(F32),
+                    m["norm_eps"])
 
 
 def _logits(params, h, rows, m, mode):
@@ -176,29 +204,31 @@ def _logits(params, h, rows, m, mode):
     return mm(picked, head)
 
 
-@functools.partial(jax.jit, static_argnames=("mkey", "control"))
-def _gaps(params, tokens, rows, served, mkey, control):
+@functools.partial(jax.jit, static_argnames=("mkey", "control", "forward"))
+def _gaps(params, tokens, rows, served, mkey, control, forward):
     m = dict(mkey)
-    ref = _logits(params, hidden(params, tokens, m), rows, m, "f32")
+    ref = _logits(params, forward(params, tokens, m, "f32"), rows, m, "f32")
     best = jnp.max(ref, -1)
     gap = best - jnp.take_along_axis(ref, served[..., None], -1)[..., 0]
     if not control:
         return gap, None
-    low = _logits(params, hidden(params, tokens, m, "fp8"), rows, m, "fp8")
+    low = _logits(params, forward(params, tokens, m, "fp8"), rows, m, "fp8")
     pick = jnp.argmax(low, -1)
     return gap, best - jnp.take_along_axis(ref, pick[..., None], -1)[..., 0]
 
 
 def served_gaps(params, tokens, rows, served, m: Dict[str, Any],
-                control: bool = False):
+                control: bool = False, forward: Callable = hidden):
     """For each sequence of ``tokens`` [B, S] and each of its ``rows``
     [B, P]: how far the reference's logit of the token served after that
     row (``served`` [B, P]) lies below the reference's best, and with
     ``control`` the same for the token the fp8 forward puts first.
-    Returns numpy arrays [B, P] (the control's is None without it)."""
+    ``forward`` is a reference module's ``hidden``.  Returns numpy arrays
+    [B, P] (the control's is None without it)."""
     mkey = tuple(sorted(m.items()))
     gap, low = _gaps(params, jnp.asarray(tokens, jnp.int32),
                      jnp.asarray(rows, jnp.int32),
-                     jnp.asarray(served, jnp.int32), mkey, bool(control))
+                     jnp.asarray(served, jnp.int32), mkey, bool(control),
+                     forward)
     return (np.asarray(gap, np.float32),
             None if low is None else np.asarray(low, np.float32))

@@ -8,6 +8,25 @@ particular to it is a file found by name: its configuration
 read by ``bench/traffic/generator.py``) and one reader per metric
 (``bench/metrics/<metric>.py``, ``read(run) -> value or None``).
 
+The configuration file's keys:
+
+* ``model``: the program's architecture (``repro.configs.get_arch``), and
+  ``overrides``: fields of it replaced for this configuration;
+* ``sizes``: the published sizes under their published names, each checked
+  against the program's field that ``SIZE_FIELDS`` or the file's own
+  ``size_fields`` (``{"<published name>": "<field>"}``) maps it to;
+* ``semantics``: what the reference needs beyond the sizes;
+* ``reference`` and ``work`` (optional): modules under ``bench/``, named by
+  their path without ``.py``, that hold the model's plain forward (the
+  contract is in ``bench/reference.py``) and its work counts (in
+  ``bench/work.py``); those two files where the keys are absent;
+* ``server``: the ``DiffusionServer``'s replicas, cache cap and session
+  slots; ``check``: how many requests the check samples and its batch;
+  ``limits``: the gap statistics that decide ``correct``, each with its
+  limit;
+* the rest (``source``, ``reduced``, ``assumed``, ``departures``, ...) is
+  read by people, not by the harness.
+
 A run makes the weights on the device from ``--seed``, builds the program's
 ``DiffusionServer`` on them, and serves every live session's first turn
 (set-up, which also compiles or loads every program the window uses).  The
@@ -25,11 +44,12 @@ end-to-end ones.
 
 Once the window has closed, peak memory is read and the server freed, the
 served tokens of a seeded sample of requests are held against the plain
-reference (``bench/reference.py``), over each one's whole session history:
-at each served token, the gap between the reference's best logit and its
-logit of that token.  ``correct`` is false if a statistic of those gaps
-that the configuration's ``limits`` names exceeds its limit, if the
-sessions' histories do not add up, or if a request was lost (``verdict``).
+reference (the configuration's reference module), over each one's whole
+session history: at each served token, the gap between the reference's
+best logit and its logit of that token.  ``correct`` is false if a
+statistic of those gaps that the configuration's ``limits`` names exceeds
+its limit, if the sessions' histories do not add up, or if a request was
+lost (``verdict``).
 The control (``bench/calibrate.py`` and the tests, never the benchmark's
 runs) puts the token that the reference computed in fp8 ranks first in
 each served token's place and goes through the same ``verdict``.  Each number
@@ -43,6 +63,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
+import functools
 import gc
 import glob
 import importlib.util
@@ -153,17 +174,42 @@ def load_cell(name: str) -> Dict[str, Any]:
     }
 
 
-def load_reader(metric: str) -> Callable:
-    path = os.path.join(BENCH, "metrics", f"{metric}.py")
-    mod_spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
+def _load(path: str, name: str):
+    mod_spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str) -> Callable:
+    return _load(os.path.join(BENCH, "metrics", f"{metric}.py"),
+                 "bench_metric_" + metric.replace(".", "_")).read
+
+
+@functools.lru_cache(maxsize=None)
+def bench_module(name: str):
+    """``bench/<name>.py``, loaded by path once a process, so that a
+    function of it keeps its identity (and its compiled programs) across
+    runs."""
+    return _load(os.path.join(BENCH, f"{name}.py"),
+                 "bench_" + name.replace("/", "_").replace(".", "_"))
+
+
+# The modules a configuration file may name, by its key, and the module
+# where it names none.
+DEFAULT_MODULES = {"reference": reference, "work": work}
+
+
+def config_module(config: Dict[str, Any], key: str):
+    """The module the configuration file names under ``key``
+    (``bench/<name>.py``), else ``DEFAULT_MODULES[key]``."""
+    name = config.get(key)
+    return DEFAULT_MODULES[key] if name is None else bench_module(name)
 
 
 # The configuration file's stated sizes (the published names) and the
-# program's fields they must equal.
+# program's fields they must equal; a file adds its own names under
+# ``size_fields``.
 SIZE_FIELDS = {
     "num_hidden_layers": "num_layers", "hidden_size": "d_model",
     "intermediate_size": "d_ff", "num_attention_heads": "num_heads",
@@ -179,8 +225,16 @@ def model_config(config: Dict[str, Any]):
     from repro.configs import get_arch
     cfg = dataclasses.replace(get_arch(config["model"]),
                               **config.get("overrides", {}))
+    fields = {**SIZE_FIELDS, **config.get("size_fields", {})}
     for key, want in config["sizes"].items():
-        got = getattr(cfg, SIZE_FIELDS[key])
+        if key not in fields:
+            raise ValueError(f"{config['model']}: no field for the size "
+                             f"{key!r}; map it under size_fields")
+        if not hasattr(cfg, fields[key]):
+            raise ValueError(f"{config['model']}: {key} maps to "
+                             f"{fields[key]!r}, which the program's "
+                             f"configuration lacks")
+        got = getattr(cfg, fields[key])
         if got != want:
             raise ValueError(f"{config['model']}: {key} runs as {got}, the "
                              f"configuration file states {want}")
@@ -188,11 +242,10 @@ def model_config(config: Dict[str, Any]):
 
 
 def reference_sizes(cfg, config: Dict[str, Any]) -> Dict[str, Any]:
-    """What the reference needs: the sizes, and the semantics the
-    configuration states."""
-    m = {k: getattr(cfg, k) for k in (
-        "d_model", "num_heads", "num_kv_heads", "head_dim", "vocab_size",
-        "rope_theta", "norm_eps", "moe_top_k")}
+    """What the reference needs: the fields its module names (``FIELDS``),
+    and the semantics the configuration states."""
+    fields = config_module(config, "reference").FIELDS
+    m = {k: getattr(cfg, k) for k in fields}
     m.update(config["semantics"])
     return m
 
@@ -396,12 +449,12 @@ def build_chains(everything: List[Served], vocab: int) -> Optional[str]:
 # ------------------------------------------------------------------ check
 def check(weights, m: Dict[str, Any], everything: List[Served],
           sample: List[Served], cap: int, batch: int,
-          control: bool) -> tuple:
+          control: bool, forward: Callable) -> tuple:
     """Statistics (``gap_stats``) of the gap, at every served token in the
     sampled requests' session histories, between the reference's best logit
     and its logit of the served token; and, with ``control``, the same with
     the token that the fp8 forward ranks first put in each served token's
-    place (else None)."""
+    place (else None).  ``forward`` is the reference module's ``hidden``."""
     by_id = {id(s): s for s in everything}
     seqs = []
     for s in sample:
@@ -426,7 +479,7 @@ def check(weights, m: Dict[str, Any], everything: List[Served],
             served[j, :len(t)] = t
             valid[j, :len(r)] = True
         gap, ctl = reference.served_gaps(weights, tokens, rows, served, m,
-                                         control=control)
+                                         control=control, forward=forward)
         n = len(seqs) - b
         gaps += [gap[j][valid[j]] for j in range(min(n, batch))]
         if control:
@@ -492,7 +545,8 @@ def pick_sample(window: List[Served], n: int, seed: int,
 
 # ------------------------------------------------------------------ run
 class Run:
-    """What a metric reader sees."""
+    """What a metric reader sees; ``work`` is the configuration's work
+    counts module (``decode_call``, ``prefill_call``)."""
 
     def __init__(self, **kw: Any):
         self.__dict__.update(kw)
@@ -588,6 +642,7 @@ def run_cell(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         traced = trace_reduce.load(files[0])
         shutil.rmtree(rec["trace_dir"], ignore_errors=True)
     run = Run(cfg=cfg, config=config, mix=mix, seconds=seconds,
+              work=config_module(config, "work"),
               device_kind=devices[0].device_kind,
               setup_s=setup_s, stats=stats, window=rec,
               served=rec["served"], trace=traced, spans=spans.done)
@@ -611,7 +666,8 @@ def run_cell(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         sample = pick_sample(run.served, int(config["check"]["requests"]),
                              seed, mix["prompt_lens"])
         got, ctl = check(weights, m, everything, sample, cap,
-                         int(config["check"]["batch"]), control)
+                         int(config["check"]["batch"]), control,
+                         config_module(config, "reference").hidden)
         log(f"check: {len(sample)} requests, {got['tokens_checked']} served "
             f"tokens held to the reference: " + _fmt(got))
         if ctl is not None:

@@ -2,14 +2,16 @@
 on the CPU: a departure of either shows here, not on the chip."""
 
 import dataclasses
+import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import cells  # noqa: F401  (puts bench/ and src/ on the path)
+import cells  # puts bench/ and src/ on the path
 import reference
+import run
 from repro.configs import get_arch
 from repro.models import param_specs
 from repro.models.layers import logits_head
@@ -97,3 +99,39 @@ def test_seed_keeps_every_bit():
     b = reference.seed_key(2**31 + 5 + 2**32)
     assert not np.array_equal(np.asarray(jax.random.key_data(a)),
                               np.asarray(jax.random.key_data(b)))
+
+
+def test_a_vector_that_is_no_norm_scale_draws():
+    """A bias (a router's correction bias) draws N(0, 0.1); a norm scale
+    1 + N(0, 0.1), a matrix N(0, 1) over sqrt(fan-in), as before."""
+    p = reference.make_weights(jax.eval_shape(lambda: {
+        "bias": jnp.zeros((4096,), jnp.float32),
+        "norm": {"scale": jnp.zeros((4096,), jnp.bfloat16)},
+        "w": jnp.zeros((64, 512), jnp.bfloat16)}), 2**31 + 9)
+    bias = np.asarray(p["bias"])
+    assert bias.dtype == np.float32
+    assert abs(bias.mean()) < 0.01 and 0.09 < bias.std() < 0.11
+    scale = np.asarray(p["norm"]["scale"], np.float32)
+    assert abs(scale.mean() - 1.0) < 0.01 and 0.09 < scale.std() < 0.11
+    w = np.asarray(p["w"], np.float32)
+    assert 0.9 / 8 < w.std() < 1.1 / 8
+
+
+# sha256 of every leaf's bytes, in tree order, as the harness drew them
+# before a 1-D leaf other than a norm scale could draw.
+WEIGHT_DIGESTS = {
+    "internlm2-1.8b.chat-swap":
+        "8c7910d3d96400dbec380a274b7d6705e8e7016e069cde1b1e9decab53870e5c",
+    "olmoe-1b-7b.chat-burst":
+        "ddba6c504be638e713bf17c6078d24a939ab83495cf1fd264da48ba94d61909f",
+}
+
+
+@pytest.mark.parametrize("name", sorted(WEIGHT_DIGESTS))
+def test_committed_configurations_draw_the_same_weights(name):
+    cfg = run.model_config(cells.small_cell(name)["config"])
+    p = reference.make_weights(param_specs(cfg), 2**31 + 7)
+    digest = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(p):
+        digest.update(np.asarray(leaf).tobytes())
+    assert digest.hexdigest() == WEIGHT_DIGESTS[name]

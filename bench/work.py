@@ -18,6 +18,18 @@ but never read it above 100%:
 ``m`` is any object with the architecture's sizes as attributes
 (``d_model``, ``num_layers``, ``num_heads``, ``num_kv_heads``, ``head_dim``,
 ``d_ff``, ``vocab_size``, ``num_experts``, ``moe_top_k``).
+
+A work counts module.  These counts are for a uniform stack of
+grouped-query attention and SwiGLU FFNs or experts.  A configuration file
+whose model counts otherwise names its own module (``"work": "<path under
+bench/ without .py>"``; this file where it names none), which the harness
+loads by path and hands to the metric readers as ``run.work``.  Such a
+module provides ``decode_call(m, contexts)`` and ``prefill_call(m,
+prompt_len)``, each returning ``(FLOPs, bytes)`` with the meaning given
+below, counted by the rules above: what the model's algorithm needs, never
+what one implementation of it happens to do.  ``m`` is the program's
+configuration.  The chip's peaks (``peaks``) and the least time
+(``needed_seconds``) stay here, in one copy.
 """
 
 from __future__ import annotations
